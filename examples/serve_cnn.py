@@ -34,6 +34,9 @@ import sys
 from repro.launch.serve import main
 
 if __name__ == "__main__":
-    argv = sys.argv[1:] or ["--arch", "alexnet", "--policy", "kom_int14",
-                            "--requests", "10", "--buckets", "1,4,8"]
+    # --reduced: the CPU demo serves the small twin; the published widths
+    # run on the chip (chip_smoke.py).
+    argv = ["--reduced"] + (sys.argv[1:] or [
+        "--arch", "alexnet", "--policy", "kom_int14", "--requests", "10",
+        "--buckets", "1,4,8"])
     sys.exit(main(argv))

@@ -9,6 +9,7 @@ import sys
 from repro.launch.serve import main
 
 if __name__ == "__main__":
-    argv = sys.argv[1:] or ["--arch", "granite-3-2b", "--requests", "6",
-                            "--slots", "3", "--max-new", "10"]
+    argv = ["--reduced"] + (sys.argv[1:] or [
+        "--arch", "granite-3-2b", "--requests", "6", "--slots", "3",
+        "--max-new", "10"])
     sys.exit(main(argv))

@@ -365,7 +365,7 @@ def _measure_paths(paths, *, kh, kw, stride, h, cin, cout, padding, policy,
     import numpy as np
 
     from .substrate import conv2d, quantize_weight
-    from .tuning import _time_call
+    from .tuning import _first_line, _time_call
 
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((1, h, h, cin)), jnp.float32)
@@ -387,8 +387,9 @@ def _measure_paths(paths, *, kh, kw, stride, h, cin, cout, padding, policy,
             us_f = _time_call(fused, x, w, iters=iters)
             us_u = _time_call(unfused, x, w, iters=iters)
         except Exception as e:  # engine infeasible here: prune, keep going
-            if verbose:
-                print(f"    {path}: failed ({type(e).__name__})")
+            # Always said, verbose or not: a kernel the compiler refuses
+            # must not vanish silently from an explore run.
+            print(f"    {path}: pruned ({_first_line(e)})")
             continue
         if verbose:
             print(f"    {path}: fused {us_f:.1f} us, unfused {us_u:.1f} us")

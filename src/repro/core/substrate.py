@@ -201,6 +201,7 @@ def limb_partials_presplit(
     variant: Variant = "karatsuba",
     narrow_dtype=jnp.int8,
     accum_dtype=jnp.int32,
+    wide: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The narrow MXU passes over ALREADY-SPLIT limb planes.
 
@@ -209,17 +210,23 @@ def limb_partials_presplit(
     transformed planes are no longer balanced digits of anything -- they
     must be contracted as-is.  This is the pass schedule shared with
     :func:`limb_partials`, minus the split.  ``narrow_dtype`` must hold the
-    digit sums (int8 for fresh balanced digits under the guard bit; int16
-    for transformed planes, whose entries grow past s8).
+    digit sums (int8 for fresh balanced digits under the guard bit); planes
+    that outgrow int8 pass ``wide=True``, which runs every pass as a
+    :func:`wide_int_dot` (three int8 passes, int32 result).
     """
     if variant not in PASS_COUNTS:
         raise ValueError(f"unknown variant: {variant}")
-    dot = functools.partial(
-        lax.dot_general,
-        dimension_numbers=dimension_numbers,
-        preferred_element_type=accum_dtype,
-    )
-    nd = lambda t: t.astype(narrow_dtype)
+    if wide:
+        dot = functools.partial(wide_int_dot,
+                                dimension_numbers=dimension_numbers)
+        nd = lambda t: t
+    else:
+        dot = functools.partial(
+            lax.dot_general,
+            dimension_numbers=dimension_numbers,
+            preferred_element_type=accum_dtype,
+        )
+        nd = lambda t: t.astype(narrow_dtype)
     p_hh = dot(nd(ah), nd(bh))
     p_ll = dot(nd(al), nd(bl))
     if variant == "karatsuba":
@@ -277,6 +284,21 @@ def limb_recombine(
         + p_mid.astype(dtype) * beta
         + p_ll.astype(dtype)
     )
+
+
+def wide_int_dot(a: jax.Array, b: jax.Array,
+                 dimension_numbers=MATMUL_DNUMS) -> jax.Array:
+    """Exact int32 dot_general of integer operands too wide for int8.
+
+    One Karatsuba level on balanced base-2^7 digits (three int8 passes,
+    |a|, |b| <= kom_qmax(7)) recombined in int32 -- exact whenever the true
+    result fits int32 (int32 adds wrap mod 2^32 in between).  The TPU's MXU
+    has int8 passes but no int16 one; this is how the Winograd engine's
+    transformed planes (|.| <= 18h) reach it.
+    """
+    p_hh, p_mid, p_ll = limb_partials(a, b, dimension_numbers,
+                                      variant="karatsuba", base_bits=7)
+    return p_hh * (1 << 14) + p_mid * (1 << 7) + p_ll
 
 
 def limb_dot_general(

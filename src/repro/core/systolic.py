@@ -87,12 +87,15 @@ def conv2d_im2col(
     kh, kw, cin, cout = w.shape
     ho, wo, pads = conv_pads(x.shape[1], x.shape[2], kh, kw, stride, padding)
     xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, 0)))
-    # im2col patches: (n, out_h, out_w, kh*kw*cin)
+    # im2col patches: (n, out_h, out_w, kh*kw*cin).  The extractor is a
+    # convolution with a one-hot kernel; HIGHEST keeps a TPU from running
+    # it as one bf16 pass, which would round every patch value to bf16.
     patches = lax.conv_general_dilated_patches(
         xp.transpose(0, 3, 1, 2),  # NCHW for the patch extractor
         filter_shape=(kh, kw),
         window_strides=(stride, stride),
         padding="VALID",
+        precision=lax.Precision.HIGHEST,
     )  # (n, cin*kh*kw, out_h, out_w)
     n, ck, oh, ow = patches.shape
     cols = patches.transpose(0, 2, 3, 1).reshape(n * oh * ow, ck)

@@ -20,8 +20,8 @@ knob end to end:
 * **Measured sweep** (:func:`tune_layer` / :func:`tune_model`): time the
   real conv entry points over the feasible candidates ON THIS BACKEND and
   persist the argmin.
-* **Persistent cache**: JSON under ``benchmarks/tuned/`` (``default.json``
-  is committed; ``*.local.json`` is gitignored), keyed by
+* **Persistent cache**: ``benchmarks/tuned/default.json`` (committed; the
+  only file tiles are read from), keyed by
   :func:`layer_key` = kind | variant/base_bits | layer geometry | backend.
   Atomic tmp+rename writes, round-trip tested.  The same cache also holds
   the DISPATCH schema: the thin-stem channel threshold
@@ -50,6 +50,10 @@ VMEM_BYTES = 16 * 2**20
 VMEM_BUDGET = int(0.75 * VMEM_BYTES)
 
 _INT_VARIANTS = ("karatsuba", "schoolbook")
+
+#: Lanes of one vreg: a Pallas conv kernel stages its input in chunks this
+#: wide (``kernels.conv2d.conv2d.staging_shape``).
+LANES = 128
 
 CACHE_ENV = "REPRO_TUNED_DIR"
 DEFAULT_CACHE_NAME = "default.json"
@@ -164,6 +168,9 @@ def feasible(kind: str, *, kh, kw, stride, h, cin, cout, variant,
                        f"pooled epilogue, not {kind!r}")
     if kind == "winograd":
         bt, bc = block
+        if min(bc, cout) > LANES:
+            return False, (f"bc={bc}: winograd's strided output stores take "
+                           f"at most {LANES} lanes")
         if kh != 3 or kw != 3 or stride != 1:
             return False, f"winograd needs 3x3/s1, got k{kh}x{kw} s{stride}"
         if variant in _INT_VARIANTS:
@@ -184,6 +191,12 @@ def feasible(kind: str, *, kh, kw, stride, h, cin, cout, variant,
             cap = _max_cin_block(kh, kw, variant, base_bits)
             if min(bk, cin) > cap:
                 return False, f"bk={bk}: one K step would wrap int32 (cap {cap})"
+        if fusion in ("pool", "pool_quant") and min(bc, cout) > LANES:
+            return False, (f"bc={bc}: the pooled epilogue's strided loads "
+                           f"take at most {LANES} lanes")
+        if min(bk, cin) > LANES and min(bk, cin) % LANES:
+            return False, (f"bk={min(bk, cin)}: a K chunk wider than {LANES} "
+                           f"lanes must be a multiple of {LANES} (staging)")
         used = implicit_vmem_bytes(kh=kh, kw=kw, stride=stride, w_img=h,
                                    cin=cin, cout=cout, bm=bm, bc=bc, bk=bk,
                                    variant=variant, fusion=fusion)
@@ -226,14 +239,16 @@ def default_block(kind: str, *, kh, kw, stride, h, cin, cout, variant,
         bk = _roundup(-(-cin // nk), 8)
     if variant in _INT_VARIANTS:
         bk = min(bk, _max_cin_block(kh, kw, variant, base_bits))
+    if bk > LANES:  # whole lane tiles: the kernels stage 128-lane chunks
+        bk = bk // LANES * LANES
     # Shrink the K chunk, then the Cout tile, then the row block (down to
     # its halo floor), until the model says it fits.
     def used(b):
         return implicit_vmem_bytes(kh=kh, kw=kw, stride=stride, w_img=h,
                                    cin=cin, cout=cout, bm=b[0], bc=b[1],
                                    bk=b[2], variant=variant)
-    while used((bm, bc, bk)) > VMEM_BUDGET and bk > 128:
-        bk = _roundup(bk // 2, 8)
+    while used((bm, bc, bk)) > VMEM_BUDGET and bk > LANES:
+        bk = max(bk // 2 // LANES * LANES, LANES)
     while used((bm, bc, bk)) > VMEM_BUDGET and bc > 128:
         bc = _roundup(bc // 2, 8)
     bm_floor = 1
@@ -436,22 +451,16 @@ def stem_cin(backend: Optional[str] = None) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _load_cache(stamp: tuple) -> TuneCache:
-    merged = TuneCache(stamp[0][0] if stamp else DEFAULT_CACHE_NAME)
-    for path_str, _mtime in stamp:
-        merged.entries.update(TuneCache.load(path_str).entries)
-    return merged
+def _load_cache(path_str: str, mtime: float) -> TuneCache:
+    return TuneCache.load(path_str)
 
 
 def _cache() -> TuneCache:
-    """The committed default cache overlaid by any ``*.local.json`` files
-    (machine-local measurements, gitignored) -- local entries win."""
-    d = tuned_dir()
-    paths = [d / DEFAULT_CACHE_NAME]
-    if d.exists():
-        paths += sorted(p for p in d.glob("*.local.json"))
-    stamp = tuple((str(p), p.stat().st_mtime) for p in paths if p.exists())
-    return _load_cache(stamp)
+    """The committed cache, ``default.json`` -- the one source of measured
+    tiles, so what runs is what the repository holds (keyed by mtime so a
+    rewrite is seen)."""
+    p = tuned_dir() / DEFAULT_CACHE_NAME
+    return _load_cache(str(p), p.stat().st_mtime if p.exists() else 0.0)
 
 
 def resolve_block(kind: str, *, kh, kw, stride, h, cin, cout, variant,
@@ -517,6 +526,13 @@ def _time_call(fn, *args, iters: int = 3, warmup: int = 1) -> float:
         jax.block_until_ready(fn(*args))
         ts.append((time.perf_counter() - t0) * 1e6)
     return float(np.median(ts))
+
+
+def _first_line(e: BaseException) -> str:
+    """``Type: first line of the message`` -- how a pruned candidate is
+    reported."""
+    msg = str(e).strip().splitlines()
+    return f"{type(e).__name__}: {msg[0] if msg else ''}"
 
 
 def tune_layer(kind: str, *, kh, kw, stride, h, cin, cout, variant,
@@ -586,8 +602,9 @@ def tune_layer(kind: str, *, kh, kw, stride, h, cin, cout, variant,
         try:
             us = _time_call(fn, x, w, iters=iters)
         except Exception as e:  # infeasible at runtime: skip, keep tuning
-            if verbose:
-                print(f"  {block}: failed ({type(e).__name__})")
+            # Always said, verbose or not: a tile the compiler refuses must
+            # not vanish silently from a tune run.
+            print(f"  {block}: pruned ({_first_line(e)})")
             continue
         if verbose:
             print(f"  {block}: {us:.1f} us")
@@ -664,12 +681,12 @@ def tune_config(cfg, *, iters: int = 2, cache_path=None,
     backend and the argmin persisted, so the engine's jitted forward picks
     the tuned tiles up through :func:`resolve_block` at trace time.
 
-    Writes go to ``measured.local.json`` (gitignored, overlaid over the
-    committed default by :func:`resolve_block`) -- an engine build must
-    never dirty the version-controlled ``default.json``; refreshing THAT is
-    the explicit ``python -m repro.core.tuning --tune`` operator action.
+    Writes go to the committed cache, ``default.json``: its keys are
+    backend-scoped, so a sweep on one backend adds entries beside the
+    other backends' without touching them, and the result is committed
+    like any other tile choice.
     """
-    path = cache_path or (tuned_dir() / "measured.local.json")
+    path = cache_path or (tuned_dir() / DEFAULT_CACHE_NAME)
     cache = TuneCache.load(path)
     variant, base_bits = _policy_variant(cfg.policy)
     for layer in conv_layer_shapes(cfg):

@@ -43,6 +43,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.substrate import limb_partials, limb_recombine
 
@@ -75,29 +76,73 @@ def int_accum_bound(kh: int, kw: int, cin: int, *, variant: str,
     return limb_term_bound(variant, base_bits) * kh * kw * cin
 
 
+#: Lanes of one vreg: a staged channel chunk is at most this wide.
+LANES = 128
+
+
+def staging_shape(rows: int, width: int, channels: int) -> tuple:
+    """Shape of the VMEM buffer :func:`stage_rows` fills.
+
+    (nc, rows, width, lc) with nc * lc == channels: Mosaic lowers a
+    strided load only from a buffer at most one lane tile wide, so wider
+    channel counts are split into 128-lane chunks.
+    """
+    if channels <= LANES:
+        return (1, rows, width, channels)
+    assert channels % LANES == 0, (
+        f"channels={channels}: a staged block wider than {LANES} lanes must "
+        f"be a multiple of {LANES}")
+    return (channels // LANES, rows, width, LANES)
+
+
+def staging_dtype(dtype):
+    """32-bit twin of ``dtype``: Mosaic strided loads need 32-bit data."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+
+
+def stage_rows(xs_ref, x0_ref, x1_ref) -> None:
+    """Copy the dual row-block binding (blocks i and i+1) into ``xs_ref``.
+
+    Every conv kernel binds its input twice so a row block plus its halo
+    is resident; staging both halves in one buffer turns every tap into a
+    ref load (:func:`load_window`) -- Mosaic has no strided vector slice.
+    """
+    nc, lc = xs_ref.shape[0], xs_ref.shape[-1]
+    r = x0_ref.shape[1]
+    for c in range(nc):
+        lanes = pl.ds(c * lc, lc)
+        xs_ref[c, pl.ds(0, r)] = x0_ref[0, :, :, lanes].astype(xs_ref.dtype)
+        xs_ref[c, pl.ds(r, r)] = x1_ref[0, :, :, lanes].astype(xs_ref.dtype)
+
+
+def load_window(xs_ref, r0: int, nr: int, c0: int, ncols: int,
+                stride: int) -> jax.Array:
+    """Rows r0 + stride*i and columns c0 + stride*j of the staged buffer,
+    all channels: (nr, ncols, channels)."""
+    parts = [xs_ref[c, pl.ds(r0, nr, stride=stride),
+                    pl.ds(c0, ncols, stride=stride), :]
+             for c in range(xs_ref.shape[0])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
 def _conv_kernel(
     *refs, kh, kw, stride, bh, wo, variant, base_bits, has_scale,
 ):
     it = iter(refs)
     x0_ref, x1_ref, w_ref = next(it), next(it), next(it)
     scale_ref = next(it) if has_scale else None
-    o_ref = next(it)
+    o_ref, xs_ref = next(it), next(it)
     bc = o_ref.shape[-1]
 
     # Two row-blocks give bh*stride*2 input rows: enough for the halo since
     # bh*stride >= (kh - stride) is checked at call time.
-    x = jnp.concatenate([x0_ref[0], x1_ref[0]], axis=0)  # (2*bh*s, W, Cin)
+    stage_rows(xs_ref, x0_ref, x1_ref)
 
     def taps():
         for dy in range(kh):
             for dx in range(kw):
-                yield jax.lax.slice(
-                    x,
-                    (dy, dx, 0),
-                    (dy + (bh - 1) * stride + 1,
-                     dx + (wo - 1) * stride + 1, x.shape[2]),
-                    (stride, stride, 1),
-                ), w_ref[dy, dx]  # (bh, wo, Cin), (Cin, bc)
+                yield (load_window(xs_ref, dy, bh, dx, wo, stride),
+                       w_ref[dy, dx])  # (bh, wo, Cin), (Cin, bc)
 
     if variant == "native":
         out = jnp.zeros((bh, wo, bc), jnp.float32)
@@ -132,7 +177,7 @@ def _conv_kernel(
     # contract (DESIGN.md section 7.3).  The pallas output materialization
     # is what pins fl(raw*scale) before the bias add.
     if has_scale:
-        out = out * scale_ref[...]          # (1, bc) broadcasts over (bh, wo, bc)
+        out = out * scale_ref[0]            # (1, bc) broadcasts over (bh, wo, bc)
     o_ref[0] = out
 
 
@@ -203,13 +248,17 @@ def conv2d_systolic_raw(
     operands = [x, x, w]  # x bound twice: row-blocks i and i+1 form the halo
     if scale is not None:
         assert scale.shape == (n, cout), (scale.shape, (n, cout))
-        in_specs.append(pl.BlockSpec((1, bc), lambda i, j, c: (i, c)))
-        operands.append(scale.astype(jnp.float32))
+        # (n, 1, cout) so the block's last two dims are (full 1, bc): the
+        # TPU (8, 128) tiling rule refuses a (1, bc) block of (n, cout).
+        in_specs.append(pl.BlockSpec((1, 1, bc), lambda i, j, c: (i, 0, c)))
+        operands.append(scale.astype(jnp.float32).reshape(n, 1, cout))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, wo, bc), lambda i, j, c: (i, j, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(staging_shape(2 * row_rows, wdim, cin),
+                                   staging_dtype(x.dtype))],
         interpret=interpret,
     )(*operands)

@@ -72,7 +72,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.karatsuba import float_split
 from repro.core.substrate import limb_partials, limb_recombine
 
-from .conv2d import int_accum_bound, limb_term_bound
+from .conv2d import (LANES, int_accum_bound, limb_term_bound, load_window,
+                     stage_rows, staging_dtype, staging_shape)
 
 _CIN_DNUMS = (((2,), (0,)), ((), ()))  # (bm, WO, bk) x (bk, bc)
 
@@ -151,6 +152,8 @@ def _implicit_kernel(
         acc_hh, acc_mid, acc_ll, acc_f = next(it), next(it), next(it), next(it)
     else:
         acc_f = next(it)
+    xs_ref = next(it)
+    ss_ref = next(it) if handoff else None
     # Conv rows this invocation must produce: the pool fusion's window
     # overhang past the row block ((pw - ps) extra rows) reads the SAME
     # dual halo binding the conv taps already need.
@@ -167,18 +170,19 @@ def _implicit_kernel(
             acc_ll[...] = jnp.zeros_like(acc_ll)
 
     # Dual row-block binding (index maps i and i+1): 2*bm*stride input rows
-    # cover the bm output rows plus the kh-stride halo.
-    x = jnp.concatenate([x0_ref[0], x1_ref[0]], axis=0)  # (2*bm*s, Wp, bk)
+    # cover the bm output rows plus the kh-stride halo, staged in one VMEM
+    # buffer so each tap is a (strided) ref load.
+    stage_rows(xs_ref, x0_ref, x1_ref)  # (2*bm*s, Wp, bk)
+    if handoff:
+        rr = s0_ref.shape[1]
+        ss_ref[pl.ds(0, rr)] = s0_ref[0]
+        ss_ref[pl.ds(rr, rr)] = s1_ref[0]  # (2*bm*s, Wp)
 
     def taps():
         for dy in range(kh):
             for dx in range(kw):
-                yield dy, dx, jax.lax.slice(
-                    x,
-                    (dy, dx, 0),
-                    (dy + (bm_eff - 1) * stride + 1,
-                     dx + (wo - 1) * stride + 1, x.shape[2]),
-                    (stride, stride, 1),
+                yield dy, dx, load_window(
+                    xs_ref, dy, bm_eff, dx, wo, stride,
                 ), w_ref[dy, dx]  # (bm_eff, wo, bk), (bk, bc)
 
     if handoff:
@@ -187,13 +191,10 @@ def _implicit_kernel(
         # an exact int dot recombined IMMEDIATELY and folded into acc_f
         # through the tap's scale plane -- K-step outer, taps inner, the
         # f32 accumulation order the lax mirror reproduces bitwise.
-        sx = jnp.concatenate([s0_ref[0], s1_ref[0]], axis=0)  # (2*bm*s, Wp)
         for dy, dx, rows, wtap in taps():
             q = rows.astype(jnp.int32)
-            stap = jax.lax.slice(
-                sx, (dy, dx),
-                (dy + (bm_eff - 1) * stride + 1, dx + (wo - 1) * stride + 1),
-                (stride, stride))  # (bm_eff, wo)
+            stap = ss_ref[pl.ds(dy, bm_eff, stride=stride),
+                          pl.ds(dx, wo, stride=stride)]  # (bm_eff, wo)
             p_hh, p_mid, p_ll = limb_partials(
                 q, wtap, _CIN_DNUMS, variant=variant, base_bits=base_bits)
             rec = limb_recombine(p_hh, p_mid, p_ll,
@@ -260,15 +261,14 @@ def _implicit_kernel(
             # wrapper).
             ridx = row_block * bm + jax.lax.broadcasted_iota(
                 jnp.int32, out.shape, 0)
-            out = jnp.where(ridx < out_rows, out, -jnp.inf)
+            # The window taps are strided loads from the (now spent) f32
+            # accumulator: Mosaic has no strided vector slice.
+            acc_f[...] = jnp.where(ridx < out_rows, out, -jnp.inf)
             pooled = None
             for py in range(pw):
                 for px in range(pw):
-                    m = jax.lax.slice(
-                        out, (py, px, 0),
-                        (py + (bm_p - 1) * ps + 1,
-                         px + (wo_p - 1) * ps + 1, out.shape[2]),
-                        (ps, ps, 1))
+                    m = acc_f[pl.ds(py, bm_p, stride=ps),
+                              pl.ds(px, wo_p, stride=ps), :]
                     pooled = m if pooled is None else jnp.maximum(pooled, m)
             out = pooled
         o_ref[0] = out
@@ -277,8 +277,8 @@ def _implicit_kernel(
 def _scale_rows(ascale_refs, bm_eff):
     """First bm_eff per-patch scale rows from the (dual, under pool) binding."""
     if len(ascale_refs) == 1:
-        return ascale_refs[0][0]
-    s = jnp.concatenate([ascale_refs[0][0], ascale_refs[1][0]], axis=0)
+        return ascale_refs[0][0, 0]
+    s = jnp.concatenate([ascale_refs[0][0, 0], ascale_refs[1][0, 0]], axis=0)
     return jax.lax.slice(s, (0, 0), (bm_eff, s.shape[1]))
 
 
@@ -368,6 +368,7 @@ def conv2d_implicit_raw(
     if pool is not None:
         pw, ps = pool
         assert bm % ps == 0, (bm, ps)
+        assert bc <= LANES, f"bc={bc}: pooled strided loads take <= {LANES}"
         bm_eff = bm + (pw - ps)
         bm_p, wo_p = bm // ps, (wo - pw) // ps + 1
         assert out_rows is not None and 0 < out_rows <= ho
@@ -408,16 +409,21 @@ def conv2d_implicit_raw(
     elif ascale is not None:
         assert ascale.shape == (n, ho, wo), (ascale.shape, (n, ho, wo))
         assert wscale is not None and wscale.shape == (1, cout)
-        in_specs.append(pl.BlockSpec((1, bm, wo), lambda b, i, j, c: (b, i, 0)))
+        # Scales as (n, ho/bm, bm, wo): the block's last two dims are then
+        # the array's full (bm, wo), which the TPU tiling rule admits for
+        # any bm.
+        in_specs.append(pl.BlockSpec((1, 1, bm, wo),
+                                     lambda b, i, j, c: (b, i, 0, 0)))
         if pool is not None:
             # The pool overhang's conv rows need the NEXT row block's
             # per-patch scales too -- dual-bind like x.
             in_specs.append(pl.BlockSpec(
-                (1, bm, wo),
-                lambda b, i, j, c, nb=n_row_blocks: (b, jnp.minimum(i + 1, nb - 1), 0),
+                (1, 1, bm, wo),
+                lambda b, i, j, c, nb=n_row_blocks: (
+                    b, jnp.minimum(i + 1, nb - 1), 0, 0),
             ))
         in_specs.append(pl.BlockSpec((1, bc), lambda b, i, j, c: (0, j)))
-        asc = ascale.astype(jnp.float32)
+        asc = ascale.astype(jnp.float32).reshape(n, n_row_blocks, bm, wo)
         operands += ([asc, asc] if pool is not None else [asc])
         operands.append(wscale.astype(jnp.float32))
     if integer and not handoff:
@@ -425,9 +431,13 @@ def conv2d_implicit_raw(
     else:
         scratch = []
     scratch.append(pltpu.VMEM((bm_eff, wo, bc), jnp.float32))
+    scratch.append(pltpu.VMEM(staging_shape(2 * row_rows, wdim, bk),
+                              staging_dtype(x.dtype)))
+    if handoff:
+        scratch.append(pltpu.VMEM((2 * row_rows, wdim), jnp.float32))
     kwargs = {}
     if pipeline and not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
     return pl.pallas_call(

@@ -51,7 +51,7 @@ from repro.core.substrate import (
     quantize_weight,
 )
 
-from .conv2d import conv2d_systolic_raw, int_accum_bound
+from .conv2d import LANES, conv2d_systolic_raw, int_accum_bound
 from .implicit_gemm import (
     INT_VARIANTS,
     conv2d_implicit_raw,
@@ -77,16 +77,32 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+#: Output columns are padded to a multiple of this: the kernels flatten a
+#: (rows, wo, cin) tap to (rows*wo, cin) for the MXU, and Mosaic only
+#: lowers that shape cast when wo is a whole number of sublane tiles.
+_WO_ALIGN = 8
+
+
 def _plan(h, w, kh, kw, stride, padding, block_h):
-    """Shared SAME/VALID plan + row padding for the spare halo block."""
+    """Shared SAME/VALID plan + row padding for the spare halo block.
+
+    Returns (ho, wo, ho_pad, wo_pad, pads): the true output size, the
+    padded one the kernel computes (rows to the row block, columns to
+    :data:`_WO_ALIGN`) and the input pads that produce it.  Extra rows and
+    columns are zeros whose outputs are sliced off.
+    """
     ho, wo, pads = conv_pads(h, w, kh, kw, stride, padding)
     # Round HO up to the row-block, then pad rows so a spare halo block exists.
     ho_pad = -(-ho // block_h) * block_h
     rows_needed = (ho_pad // block_h + 1) * block_h * stride
     h_padded = h + pads[0][0] + pads[0][1]
     extra_rows = max(rows_needed - h_padded, 0)
-    pads = ((pads[0][0], pads[0][1] + extra_rows), pads[1])
-    return ho, wo, ho_pad, pads
+    wo_pad = -(-wo // _WO_ALIGN) * _WO_ALIGN
+    w_padded = w + pads[1][0] + pads[1][1]
+    extra_cols = max((wo_pad - 1) * stride + kw - w_padded, 0)
+    pads = ((pads[0][0], pads[0][1] + extra_rows),
+            (pads[1][0], pads[1][1] + extra_cols))
+    return ho, wo, ho_pad, wo_pad, pads
 
 
 def _resolve_block(kind, **key):
@@ -125,7 +141,7 @@ def _conv2d_systolic_core(
     block_h = min(block_h, 32)
     while block_h * stride < kh - stride:  # halo feasibility
         block_h *= 2
-    ho, wo, ho_pad, pads = _plan(h, wdim, kh, kw, stride, padding, block_h)
+    ho, wo, ho_pad, _, pads = _plan(h, wdim, kh, kw, stride, padding, block_h)
     scale = None
     if variant != "native":
         w_vals, w_scale = w.values, w.scale  # cached: no requantization
@@ -567,17 +583,20 @@ def _conv2d_implicit_core(
     else:
         while bm * stride < kh - stride:  # halo feasibility
             bm *= 2
-        ho, wo, ho_pad, pads = _plan(h, wdim, kh, kw, stride, padding, bm)
+        ho, wo, ho_pad, wo_pad, pads = _plan(h, wdim, kh, kw, stride,
+                                             padding, bm)
         ascale = cs = wsc = None
         if handoff_in:
             # The producer's padded int tensor only needs the spare-halo
-            # row padding on top; integer zero rows (scale 0) contribute
-            # exact zero to every partial and are sliced away.
-            extra = (ho_pad // bm + 1) * bm * stride - (h + 2)
-            xp = jnp.pad(x.values, ((0, 0), (0, max(extra, 0)), (0, 0),
+            # row padding and the column alignment on top; integer zero
+            # pixels (scale 0) contribute exact zero to every partial and
+            # are sliced away.
+            extra = (max((ho_pad // bm + 1) * bm * stride - (h + 2), 0),
+                     wo_pad - wo)
+            xp = jnp.pad(x.values, ((0, 0), (0, extra[0]), (0, extra[1]),
                                     (0, 0)))
             cs = jnp.pad(_cell_scales(x.scale, h + 2, wdim + 2),
-                         ((0, 0), (0, max(extra, 0)), (0, 0)))
+                         ((0, 0), (0, extra[0]), (0, extra[1])))
         else:
             xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, 0)))
             if integer:
@@ -585,10 +604,11 @@ def _conv2d_implicit_core(
                                            variant=variant,
                                            base_bits=base_bits):
                     s_tile = tile_scale_grid(xp, qmax, -(-ho_pad // 2),
-                                             -(-wo // 2))
-                    ascale = tile_scales_upsampled(s_tile, ho_pad, wo)
+                                             -(-wo_pad // 2))
+                    ascale = tile_scales_upsampled(s_tile, ho_pad, wo_pad)
                 else:
-                    ascale = _patch_scales(xp, kh, kw, stride, qmax)[:, :ho_pad]
+                    ascale = _patch_scales(xp, kh, kw, stride,
+                                           qmax)[:, :ho_pad, :wo_pad]
         pk = (-cin) % bk
         if pk:  # zero channels contribute exact zeros to every partial
             xp = jnp.pad(xp, ((0, 0), (0, 0), (0, 0), (0, pk)))
@@ -827,16 +847,27 @@ def _conv2d_winograd_core(
         else:
             bt, bc = block
         th_pad = -(-th // bt) * bt
+        # Tile columns to whole sublane tiles (the kernel flattens each
+        # point to (bt*tw, cin)); the extra tiles read zeros and are
+        # sliced off.
+        tw_pad = -(-tw // _WO_ALIGN) * _WO_ALIGN
         # One spare halo row block plus the full tile-column footprint.
         rows_needed = (th_pad // bt + 1) * 2 * bt
-        cols_needed = 2 * tw + 2
+        cols_needed = 2 * tw_pad + 2
         h_padded = h + pads[0][0] + pads[0][1]
         w_padded = wdim + pads[1][0] + pads[1][1]
         pads = ((pads[0][0], pads[0][1] + max(rows_needed - h_padded, 0)),
                 (pads[1][0], pads[1][1] + max(cols_needed - w_padded, 0)))
         xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, 0)))
-        s_tile = tile_scale_grid(xp, qmax, th_pad, tw)
+        s_tile = tile_scale_grid(xp, qmax, th_pad, tw_pad)
         uh, ul = winograd_weight_planes(w_vals, base_bits)
+        # Whole 128-lane chunks past one lane tile (the kernel stages its
+        # input in such chunks); zero channels add exact zeros.
+        pk = (-cin) % LANES if cin > LANES else 0
+        if pk:
+            xp = jnp.pad(xp, ((0, 0), (0, 0), (0, 0), (0, pk)))
+            uh = jnp.pad(uh, ((0, 0), (0, 0), (0, pk), (0, 0)))
+            ul = jnp.pad(ul, ((0, 0), (0, 0), (0, pk), (0, 0)))
         bc = min(bc, cout)
         pc = (-cout) % bc
         wsc = wscale4
@@ -845,7 +876,7 @@ def _conv2d_winograd_core(
             ul = jnp.pad(ul, ((0, 0), (0, 0), (0, 0), (0, pc)))
             wsc = jnp.pad(wsc, ((0, pc),))
         out = conv2d_winograd_raw(
-            xp, uh, ul, th=th_pad, tw=tw, block=(bt, bc),
+            xp, uh, ul, th=th_pad, tw=tw_pad, block=(bt, bc),
             variant=variant, base_bits=base_bits, qmax=qmax,
             ascale=s_tile, wscale=wsc.reshape(1, -1), interpret=interpret,
         )[:, :ho, :wo, :cout]

@@ -37,9 +37,9 @@ Exactness architecture (the bitwise differential vs implicit/im2col):
   FIRST; the linear B/G transforms apply per limb plane, exactly.  The
   transformed planes are no longer balanced digits of anything (|V| <= 4h,
   |U| <= 9h, h = 2^(b-1)), so the pointwise passes run through
-  :func:`~repro.core.substrate.limb_partials_presplit` with int16 narrow
-  passes, and BOTH weight planes ship to the kernel (re-splitting U would
-  change the integers).
+  :func:`~repro.core.substrate.limb_partials_presplit` as wide dots (three
+  int8 MXU passes each: the MXU has no int16 pass), and BOTH weight planes
+  ship to the kernel (re-splitting U would change the integers).
 * **Inverse transform before the single recombine.**  The exact int32
   pointwise partials are pushed through the integer At.m.A inverse per limb
   plane; by linearity the result is exactly 4x the direct path's per-limb
@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.substrate import (
     balanced_split,
@@ -72,7 +73,8 @@ from repro.core.substrate import (
     limb_recombine,
 )
 
-from .conv2d import int_accum_bound
+from .conv2d import (LANES, int_accum_bound, load_window, stage_rows,
+                     staging_dtype, staging_shape)
 
 # The integer F(2x2, 3x3) transform matrices (correlation convention).
 BT = ((1, 0, -1, 0), (0, 1, 1, 0), (0, -1, 1, 0), (0, 1, 0, -1))
@@ -83,10 +85,6 @@ AT = ((1, 1, 1, 0), (0, 1, -1, -1))
 WINOGRAD_OUTPUT_SCALE = 4
 
 _INT_VARIANTS = ("karatsuba", "schoolbook")
-
-#: Pointwise contraction: (4, 4, bt, tw, cin) x (4, 4, cin, bc) batched over
-#: the two point-grid axes, contracting cin.
-_POINT_DNUMS = (((4,), (2,)), ((0, 1), (0, 1)))
 
 #: Largest integer f32 represents exactly (the mirror's chunk budget).
 _F32_EXACT = 1 << 24
@@ -187,13 +185,28 @@ def _lincomb(coefs, arrs):
     return acc
 
 
+def _transform(M, g):
+    """M . g . Mt over a q x q nested list of equal-shape arrays (exact).
+
+    Returns a p x p nested list.  Lists, not a stacked point axis, so the
+    Pallas kernel can keep every Winograd point a plain 2-D tile.
+    """
+    p, q = len(M), len(M[0])
+    left = [[_lincomb(M[i], [g[a][b] for a in range(q)]) for b in range(q)]
+            for i in range(p)]
+    return [[_lincomb(M[j], [left[i][b] for b in range(q)]) for j in range(p)]
+            for i in range(p)]
+
+
+def _stack2(g):
+    return jnp.stack([jnp.stack(r) for r in g])
+
+
 def winograd_transform_2d(M, g: jax.Array) -> jax.Array:
     """M . g . Mt over the two leading point-grid axes of ``g`` (exact)."""
-    p, q = len(M), len(M[0])
-    left = [_lincomb(M[i], [g[a] for a in range(q)]) for i in range(p)]
-    out = [[_lincomb(M[j], [left[i][b] for b in range(q)]) for j in range(p)]
-           for i in range(p)]
-    return jnp.stack([jnp.stack(r) for r in out])
+    q = len(M[0])
+    return _stack2(_transform(M, [[g[a, b] for b in range(q)]
+                                  for a in range(q)]))
 
 
 def winograd_weight_planes(w_vals: jax.Array,
@@ -211,33 +224,29 @@ def winograd_weight_planes(w_vals: jax.Array,
     return uh.astype(jnp.int16), ul.astype(jnp.int16)
 
 
-def winograd_input_planes(q4: jax.Array,
-                          base_bits: int) -> tuple[jax.Array, jax.Array]:
-    """BT . d_limb . B per balanced limb plane of the stacked 4x4 tiles.
+def winograd_input_planes(q4, base_bits: int):
+    """BT . d_limb . B per balanced limb plane of the 4x4 tile points.
 
-    ``q4``: (4, 4, ...) quantized tile ints.  |V| <= 4h per plane, so the
-    whole transform runs in int16 (digits |.| <= h <= 128; same integers
-    as an int32 transform, ~2x faster elementwise on CPU and narrower in
-    VMEM on TPU).
+    ``q4``: 4x4 nested list of quantized tile ints (equal shapes).  Returns
+    (vh, vl), two 4x4 nested lists of int32 (|V| <= 4h per plane).
     """
-    dh, dl = balanced_split(q4, base_bits)
-    return (winograd_transform_2d(BT, dh.astype(jnp.int16)),
-            winograd_transform_2d(BT, dl.astype(jnp.int16)))
+    split = [[balanced_split(q, base_bits) for q in row] for row in q4]
+    return (_transform(BT, [[hl[0] for hl in row] for row in split]),
+            _transform(BT, [[hl[1] for hl in row] for row in split]))
 
 
-def winograd_inverse(m_hh: jax.Array, m_mid: jax.Array, m_ll: jax.Array, *,
-                     base_bits: int) -> jax.Array:
+def winograd_inverse(m_hh, m_mid, m_ll, *, base_bits: int):
     """At . m . A per limb plane (exact int32), then ONE f32 recombine.
 
-    ``m_*``: (4, 4, ...) int32 pointwise partials.  Returns (2, 2, ...)
-    f32 -- exactly 4x the direct path's recombined raw output (the shared
-    single ``limb_recombine`` call site of this engine, kernel AND mirror).
+    ``m_*``: 4x4 nested lists of int32 pointwise partials.  Returns a 2x2
+    nested list of f32 -- exactly 4x the direct path's recombined raw
+    output (the shared single ``limb_recombine`` call site of this engine,
+    kernel AND mirror).
     """
-    y_hh = winograd_transform_2d(AT, m_hh)
-    y_mid = winograd_transform_2d(AT, m_mid)
-    y_ll = winograd_transform_2d(AT, m_ll)
-    return limb_recombine(y_hh, y_mid, y_ll, base_bits=base_bits,
-                          dtype=jnp.float32)
+    y = [_transform(AT, m) for m in (m_hh, m_mid, m_ll)]
+    return [[limb_recombine(y[0][i][j], y[1][i][j], y[2][i][j],
+                            base_bits=base_bits, dtype=jnp.float32)
+             for j in range(2)] for i in range(2)]
 
 
 # ---------------------------------------------------------------------------
@@ -362,9 +371,10 @@ def _winograd_partials_f32(vh, vl, uh, ul, *, variant, base_bits,
             point_hh[p] = hh[gi]
             point_mid[p] = mid[gi]
             point_ll[p] = ll[gi]
-    shape = (4, 4) + spatial + (cout,)
-    stack = lambda pl_: jnp.stack(pl_).reshape(shape)
-    return stack(point_hh), stack(point_mid), stack(point_ll)
+    shape = spatial + (cout,)
+    nest = lambda pts: [[pts[4 * u + v].reshape(shape) for v in range(4)]
+                        for u in range(4)]
+    return nest(point_hh), nest(point_mid), nest(point_ll)
 
 
 def stream_conv_winograd(xp, w_vals, s_tile, *, th, tw, variant, base_bits,
@@ -383,17 +393,16 @@ def stream_conv_winograd(xp, w_vals, s_tile, *, th, tw, variant, base_bits,
     cout = w_vals.shape[-1]
     # Gather the 16 point planes: point (u, v) of tile (ty, tx) is padded
     # pixel (2*ty + u, 2*tx + v).
-    planes = [
-        [lax.slice(xp, (0, u, v, 0),
-                   (n, u + 2 * (th - 1) + 1, v + 2 * (tw - 1) + 1, cin),
-                   (1, 2, 2, 1))
+    s = s_tile[..., None]
+    q4 = [
+        [jnp.clip(jnp.round(lax.slice(
+            xp, (0, u, v, 0),
+            (n, u + 2 * (th - 1) + 1, v + 2 * (tw - 1) + 1, cin),
+            (1, 2, 2, 1)) / s), -qmax, qmax).astype(jnp.int32)
          for v in range(4)]
         for u in range(4)
-    ]
-    x4 = jnp.stack([jnp.stack(r) for r in planes])  # (4, 4, n, th, tw, cin)
-    s = s_tile[..., None]
-    q4 = jnp.clip(jnp.round(x4 / s), -qmax, qmax).astype(jnp.int32)
-    vh, vl = winograd_input_planes(q4, base_bits)
+    ]  # 4x4 points of (n, th, tw, cin)
+    vh, vl = (_stack2(v) for v in winograd_input_planes(q4, base_bits))
     # Pin the transformed planes: without the barrier XLA refuses the
     # materialization and re-runs gather+quantize+transform once per Cin
     # chunk of the partials below (pure scheduling, same integers).
@@ -404,7 +413,7 @@ def stream_conv_winograd(xp, w_vals, s_tile, *, th, tw, variant, base_bits,
         uh = ul = None
     m_hh, m_mid, m_ll = _winograd_partials_f32(
         vh, vl, uh, ul, variant=variant, base_bits=base_bits, w_ops=w_ops)
-    raw4 = winograd_inverse(m_hh, m_mid, m_ll, base_bits=base_bits)
+    raw4 = _stack2(winograd_inverse(m_hh, m_mid, m_ll, base_bits=base_bits))
     # (2, 2, n, th, tw, cout) -> (n, 2*th, 2*tw, cout)
     return raw4.transpose(2, 3, 0, 4, 1, 5).reshape(n, 2 * th, 2 * tw, cout)
 
@@ -414,37 +423,45 @@ def stream_conv_winograd(xp, w_vals, s_tile, *, th, tw, variant, base_bits,
 # ---------------------------------------------------------------------------
 
 def _winograd_kernel(x0_ref, x1_ref, uh_ref, ul_ref, ascale_ref, wscale_ref,
-                     o_ref, *, bt, tw, variant, base_bits, qmax):
+                     o_ref, xs_ref, *, bt, tw, variant, base_bits, qmax):
     # Dual row-block binding (index maps i and i+1): 4*bt padded rows cover
-    # the 2*bt + 2 rows the bt tile-rows' 4x4 footprints need.
-    x = jnp.concatenate([x0_ref[0], x1_ref[0]], axis=0)  # (4*bt, Wp, cin)
-    cin = x.shape[-1]
-    planes = [
-        [lax.slice(x, (u, v, 0),
-                   (u + 2 * (bt - 1) + 1, v + 2 * (tw - 1) + 1, cin),
-                   (2, 2, 1))
+    # the 2*bt + 2 rows the bt tile-rows' 4x4 footprints need.  Both halves
+    # are staged in one VMEM buffer so the stride-2 point gathers are
+    # strided ref loads (Mosaic has no strided vector slice).
+    stage_rows(xs_ref, x0_ref, x1_ref)  # (4*bt, Wp, cin)
+    cin = x0_ref.shape[-1]
+    s = ascale_ref[0, 0][..., None]  # (bt, tw, 1)
+    # Every Winograd point is a 2-D (bt*tw, cin) tile: the MXU contracts
+    # 2-D operands, and tw % 8 == 0 (the wrapper pads it) keeps the
+    # flatten a whole-tile shape cast.
+    q4 = [
+        [jnp.clip(jnp.round(load_window(xs_ref, u, bt, v, tw, 2) / s),
+                  -qmax, qmax).astype(jnp.int32).reshape(bt * tw, cin)
          for v in range(4)]
         for u in range(4)
     ]
-    x4 = jnp.stack([jnp.stack(r) for r in planes])  # (4, 4, bt, tw, cin)
-    s = ascale_ref[0]  # (bt, tw)
-    q4 = jnp.clip(jnp.round(x4 / s[..., None]), -qmax, qmax).astype(jnp.int32)
     vh, vl = winograd_input_planes(q4, base_bits)
     # 16 pointwise contractions over the FULL Cin (the growth bound
-    # guarantees a single wrap-free int32 group -- no K tiling, no folds),
-    # int16 narrow passes: the transformed planes outgrow int8 but their
-    # karatsuba digit sums (|Vh+Vl| <= 8h, |Uh+Ul| <= 18h) still fit int16.
-    m_hh, m_mid, m_ll = limb_partials_presplit(
-        vh, vl, uh_ref[...], ul_ref[...],
-        _POINT_DNUMS, variant=variant, narrow_dtype=jnp.int16)
+    # guarantees a single wrap-free int32 group -- no K tiling, no folds).
+    # The transformed planes outgrow int8 and the MXU has no int16 pass,
+    # so each plane product runs as three int8 passes (wide_int_dot).
+    parts = [[limb_partials_presplit(
+        vh[u][v], vl[u][v], uh_ref[u, v].astype(jnp.int32),
+        ul_ref[u, v].astype(jnp.int32), variant=variant, wide=True)
+        for v in range(4)] for u in range(4)]
+    m_hh, m_mid, m_ll = ([[parts[u][v][k] for v in range(4)]
+                          for u in range(4)] for k in range(3))
     raw4 = winograd_inverse(m_hh, m_mid, m_ll, base_bits=base_bits)
     # Fused dequant epilogue: tile scale x (per-channel scale / 4); the
     # 0.25 fold is an exact exponent shift, so this equals the direct
-    # paths' fl(raw * (s * wscale)) bitwise.
-    t = s[..., None] * wscale_ref[...]  # (bt, tw, bc)
-    out4 = raw4 * t[None, None]  # (2, 2, bt, tw, bc)
-    bc = out4.shape[-1]
-    o_ref[0] = out4.transpose(2, 0, 3, 1, 4).reshape(2 * bt, 2 * tw, bc)
+    # paths' fl(raw * (s * wscale)) bitwise.  Output point (a, b) of every
+    # tile lands on rows 2*ty + a, cols 2*tx + b: strided stores.
+    t = s * wscale_ref[...]  # (bt, tw, bc)
+    bc = t.shape[-1]
+    for a in range(2):
+        for b in range(2):
+            o_ref[0, pl.ds(a, bt, stride=2), pl.ds(b, tw, stride=2), :] = (
+                raw4[a][b].reshape(bt, tw, bc) * t)
 
 
 def conv2d_winograd_raw(
@@ -475,6 +492,7 @@ def conv2d_winograd_raw(
     cout = uh.shape[-1]
     bt, bc = block
     bc = min(bc, cout)
+    assert bc <= LANES, f"bc={bc}: strided output stores take <= {LANES} lanes"
     assert th % bt == 0, (th, bt)
     assert cout % bc == 0, (cout, bc)
     assert wdim >= 2 * tw + 2, (wdim, tw)
@@ -502,7 +520,9 @@ def conv2d_winograd_raw(
         ),
         pl.BlockSpec((4, 4, cin, bc), lambda i, j, b: (0, 0, 0, j)),
         pl.BlockSpec((4, 4, cin, bc), lambda i, j, b: (0, 0, 0, j)),
-        pl.BlockSpec((1, bt, tw), lambda i, j, b: (b, i, 0)),
+        # Tile scales as (n, th/bt, bt, tw): the block's last two dims are
+        # then the array's full (bt, tw), which the TPU tiling rule admits.
+        pl.BlockSpec((1, 1, bt, tw), lambda i, j, b: (b, i, 0, 0)),
         pl.BlockSpec((1, bc), lambda i, j, b: (0, j)),
     ]
     return pl.pallas_call(
@@ -513,5 +533,9 @@ def conv2d_winograd_raw(
                                lambda i, j, b: (b, i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, 2 * th, 2 * tw, cout),
                                        jnp.float32),
+        scratch_shapes=[pltpu.VMEM(staging_shape(4 * bt, wdim, cin),
+                                   staging_dtype(x.dtype))],
         interpret=interpret,
-    )(x, x, uh, ul, ascale.astype(jnp.float32), wscale.astype(jnp.float32))
+    )(x, x, uh, ul,
+      ascale.astype(jnp.float32).reshape(n, n_row_blocks, bt, tw),
+      wscale.astype(jnp.float32))

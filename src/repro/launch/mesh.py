@@ -9,10 +9,7 @@ import jax
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    # axis_types only exists on newer jax; older releases default to Auto.
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,16 +23,11 @@ def make_host_mesh(data: int = 1, model: int = 1):
     return jax.make_mesh((data, model), ("data", "model"), **_mesh_kwargs(2))
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (experimental import + arg names
-    on older releases).  Replication checking is disabled either way -- the
-    serving engines pass replicated params explicitly."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off -- the serving
+    engines pass replicated params explicitly."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def dp_axes(mesh) -> tuple:

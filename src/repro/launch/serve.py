@@ -230,12 +230,21 @@ def _serve_multi(cfgs, args) -> int:
     return 0 if served == want else 1
 
 
+def _enable_compile_cache() -> None:
+    """Once the arguments are valid: a refused command line leaves JAX's
+    configuration alone."""
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b",
                     help="one arch, or a comma-separated list served on one "
                          "device pool via the multi-model dispatcher")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the config's small CPU twin instead of the "
+                         "published widths")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
@@ -314,6 +323,7 @@ def main(argv=None):
     if len(cfgs) > 1:
         if any(c.family in ("encdec",) for c in cfgs):
             ap.error("the multi-model pool serves decoder-only LM families")
+        _enable_compile_cache()
         return _serve_multi(cfgs, args)
     cfg = cfgs[0]
     if cfg.family == "cnn":
@@ -330,7 +340,9 @@ def main(argv=None):
             validate_path_policy(cfg.conv_path, cfg.policy)
         except ValueError as e:
             ap.error(f"--conv-path {e}")
+        _enable_compile_cache()
         return _serve_cnn(cfg, args)
+    _enable_compile_cache()
     return _serve_lm(cfg, args)
 
 

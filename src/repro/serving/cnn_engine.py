@@ -176,11 +176,11 @@ class CNNServeEngine:
             return fwd
         from jax.sharding import PartitionSpec as P
 
-        from repro.launch.mesh import shard_map_compat
+        from repro.launch.mesh import shard_map_unchecked
         batch_spec = P(self._dp_axes, None, None, None)
         # params replicated (P() prefix over the whole tree, QWeight leaves
         # included); only the image batch axis is sharded.
-        return shard_map_compat(
+        return shard_map_unchecked(
             fwd, self.mesh,
             in_specs=(P(), batch_spec),
             out_specs=P(self._dp_axes, None),

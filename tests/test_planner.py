@@ -363,3 +363,22 @@ def test_annotate_plan_roofline():
     # model-scored entries get the floor but no achievement fraction
     out2 = annotate_plan(plan)
     assert all(e.roofline_frac is None for e in out2.entries)
+
+
+def _refused(*_a, **_k):
+    raise RuntimeError("Mosaic failed to compile TPU kernel\ndetails")
+
+
+def test_pruned_candidate_is_reported_without_verbose(monkeypatch, capsys):
+    """A candidate engine that raises is pruned AND said, type and first
+    line, even with verbose off: a kernel the chip's compiler refuses must
+    not vanish silently from an explore run."""
+    import repro.core.substrate as substrate
+    monkeypatch.setattr(substrate, "conv2d", _refused)
+    out = planner._measure_paths(
+        ["implicit"], kh=3, kw=3, stride=1, h=8, cin=16, cout=8,
+        padding="SAME", policy=MatmulPolicy.KOM_INT14, iters=1,
+        verbose=False)
+    assert out == {}
+    assert ("implicit: pruned (RuntimeError: Mosaic failed to compile TPU "
+            "kernel)") in capsys.readouterr().out

@@ -127,8 +127,6 @@ def test_hlo_parser_matches_cost_analysis():
                          jax.ShapeDtypeStruct((128, 32), jnp.float32)).compile()
     st = analyze(c.as_text())
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # newer jax returns [dict]
-        ca = ca[0]
     assert abs(st.flops - ca["flops"]) / ca["flops"] < 0.05
     assert abs(st.bytes - ca["bytes accessed"]) / ca["bytes accessed"] < 0.2
 

@@ -47,7 +47,7 @@ def test_limb_partials_recombine_is_exact_product(seed, schedule):
     qm = kom_qmax(bb)
     a = jnp.array(_ints(rng, qm, (1, 1)))
     b = jnp.array(_ints(rng, qm, (1, 1)))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         parts = limb_partials(a, b, variant=variant, base_bits=bb)
         out = int(limb_recombine(*parts, base_bits=bb, dtype=jnp.int64)[0, 0])
     assert out == int(a[0, 0]) * int(b[0, 0])
@@ -64,7 +64,7 @@ def test_limb_dot_general_exact_over_shapes(seed, schedule, m, k, n):
     qm = kom_qmax(bb)
     a = _ints(rng, qm, (m, k))
     b = _ints(rng, qm, (k, n))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(limb_dot_general(
             jnp.array(a), jnp.array(b), variant=variant, base_bits=bb,
             recombine_dtype=jnp.int64))
@@ -82,7 +82,7 @@ def test_karatsuba_equals_schoolbook(seed, schedule):
     qm = kom_qmax(bb)
     a = jnp.array(_ints(rng, qm, (4, 8)))
     b = jnp.array(_ints(rng, qm, (8, 4)))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         kara = np.asarray(limb_dot_general(
             a, b, variant="karatsuba", base_bits=bb,
             recombine_dtype=jnp.int64))

@@ -25,6 +25,7 @@ from repro.core.substrate import (
     policy_int_spec,
     prequant_dot_general,
     quantize_weight,
+    wide_int_dot,
 )
 from repro.models.cnn import (
     ALEXNET,
@@ -73,7 +74,7 @@ def test_limb_dot_exact(variant, bb):
     qm = kom_qmax(bb)
     a = rng.integers(-qm, qm + 1, (24, 48)).astype(np.int32)
     b = rng.integers(-qm, qm + 1, (48, 16)).astype(np.int32)
-    with jax.experimental.enable_x64():  # int64 recombine, bit-exact mode
+    with jax.enable_x64(True):  # int64 recombine, bit-exact mode
         out = np.asarray(limb_dot_general(
             jnp.array(a), jnp.array(b), variant=variant, base_bits=bb,
             recombine_dtype=jnp.int64))
@@ -81,11 +82,23 @@ def test_limb_dot_exact(variant, bb):
     np.testing.assert_array_equal(out, ref)
 
 
+@pytest.mark.parametrize("bound", [127, 1152, 8127])
+def test_wide_int_dot_exact(bound):
+    """Operands past int8 (the Winograd planes reach 18h = 1152) contract
+    exactly through three int8 passes and an int32 recombine."""
+    k = 64
+    a = rng.integers(-bound, bound + 1, (8, k)).astype(np.int32)
+    b = rng.integers(-bound, bound + 1, (k, 16)).astype(np.int32)
+    out = np.asarray(wide_int_dot(jnp.array(a), jnp.array(b)))
+    assert out.dtype == np.int32
+    np.testing.assert_array_equal(out, a.astype(np.int64) @ b.astype(np.int64))
+
+
 def test_limb_partials_recombine_roundtrip():
     qm = kom_qmax(7)
     a = rng.integers(-qm, qm + 1, (8, 32)).astype(np.int32)
     b = rng.integers(-qm, qm + 1, (32, 8)).astype(np.int32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         parts = limb_partials(jnp.array(a), jnp.array(b))
         out = np.asarray(limb_recombine(*parts, base_bits=7, dtype=jnp.int64))
     np.testing.assert_array_equal(out, a.astype(np.int64) @ b.astype(np.int64))

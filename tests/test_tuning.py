@@ -8,6 +8,7 @@ resolution with re-validation.
 import numpy as np
 
 from repro.core import tuning
+from repro.core.precision import MatmulPolicy
 from repro.core.tuning import (
     TuneCache,
     VMEM_BUDGET,
@@ -21,6 +22,7 @@ from repro.core.tuning import (
     resolve_block,
     systolic_vmem_bytes,
 )
+from repro.models.cnn import CNNConfig
 
 VGG_DEEP = dict(kh=3, kw=3, stride=1, h=28, cin=512, cout=512)
 
@@ -145,22 +147,29 @@ def test_resolve_block_consults_cache(tmp_path, monkeypatch):
     tuning._load_cache.cache_clear()
 
 
-def test_local_overlay_wins_over_committed_default(tmp_path, monkeypatch):
-    """`*.local.json` (machine-local measurements, gitignored) overlay the
-    committed default cache -- engine `tune=True` runs write there and must
-    never dirty the version-controlled default.json."""
+def test_tune_config_writes_the_committed_cache(tmp_path, monkeypatch):
+    """An engine-build sweep (``tune_config``) persists into the committed
+    cache file, beside other backends' entries, and resolve_block reads
+    its result back; no other file is written or read."""
     monkeypatch.setenv(tuning.CACHE_ENV, str(tmp_path))
     tuning._load_cache.cache_clear()
-    key = layer_key("implicit", **VGG_DEEP, variant="karatsuba", base_bits=7)
+    other = layer_key("implicit", **VGG_DEEP, variant="karatsuba",
+                      base_bits=7, backend="tpu")
     committed = TuneCache(tmp_path / tuning.DEFAULT_CACHE_NAME)
-    committed.put(key, (8, 128, 256), us=9.0)
+    committed.put(other, (8, 128, 256), us=9.0)
     committed.save()
-    local = TuneCache(tmp_path / "measured.local.json")
-    local.put(key, (16, 128, 128), us=1.0)
-    local.save()
-    tuning._load_cache.cache_clear()
-    assert resolve_block("implicit", **VGG_DEEP, variant="karatsuba",
-                         base_bits=7) == (16, 128, 128)
+    cfg = CNNConfig("tiny", (("conv", 3, 8, 1), ("fc", 4)), img_size=8,
+                    in_channels=8, policy=MatmulPolicy.KOM_INT14)
+    tuning.tune_config(cfg, iters=1)
+    assert sorted(p.name for p in tmp_path.iterdir()) == [
+        tuning.DEFAULT_CACHE_NAME]
+    entries = TuneCache.load(tmp_path / tuning.DEFAULT_CACHE_NAME).entries
+    assert entries[other]["block"] == [8, 128, 256]
+    layer = conv_layer_shapes(cfg)[0]
+    key = layer_key("implicit", **layer, variant="karatsuba", base_bits=7)
+    assert key in entries
+    assert resolve_block("implicit", **layer, variant="karatsuba",
+                         base_bits=7) == tuple(entries[key]["block"])
     tuning._load_cache.cache_clear()
 
 
@@ -272,3 +281,22 @@ def test_stem_cin_threshold_schema(tmp_path, monkeypatch):
     # explicit stem_cin argument bypasses the cache entirely
     assert heuristic_path(**thin, stem_cin=4) != "im2col"
     tuning._load_cache.cache_clear()
+
+
+def test_tune_layer_reports_pruned_tiles(tmp_path, monkeypatch, capsys):
+    """A tile whose call raises is pruned AND said, type and first line,
+    even with verbose off; the sweep then falls back to the default."""
+    from repro.kernels.conv2d import ops
+
+    def refused(*_a, **_k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel\ndetails")
+
+    monkeypatch.setenv(tuning.CACHE_ENV, str(tmp_path))
+    monkeypatch.setattr(ops, "conv2d_implicit", refused)
+    layer = dict(kh=3, kw=3, stride=1, h=8, cin=16, cout=8)
+    best = tuning.tune_layer("implicit", **layer, variant="karatsuba",
+                             base_bits=7, iters=1)
+    assert best == default_block("implicit", **layer, variant="karatsuba",
+                                 base_bits=7)
+    assert ("pruned (RuntimeError: Mosaic failed to compile TPU kernel)"
+            in capsys.readouterr().out)
