@@ -165,6 +165,42 @@ def cnn_layer_topology(cfg: CNNConfig) -> List[dict]:
     return out
 
 
+def cnn_layer_names(cfg: CNNConfig) -> List[str]:
+    """The published name of each entry of ``cfg.layers``, in order.
+
+    VGG numbers convs by block (``conv1_1`` ... ``conv5_3``), each pool after
+    its block (``pool1`` ... ``pool5``); AlexNet numbers convs flat
+    (``conv1`` ... ``conv5``), each pool after the conv before it (``pool1``,
+    ``pool2``, ``pool5``).  FC layers continue the count (``fc6`` ...).
+    """
+    by_block = cfg.name.startswith("vgg")
+    names: List[str] = []
+    block, in_block, n_conv, n_fc = 1, 0, 0, 0
+    for spec in cfg.layers:
+        if spec[0] == "conv":
+            n_conv += 1
+            in_block += 1
+            names.append(f"conv{block}_{in_block}" if by_block
+                         else f"conv{n_conv}")
+        elif spec[0] == "pool":
+            names.append(f"pool{block}" if by_block else f"pool{n_conv}")
+            block, in_block = block + 1, 0
+        else:
+            n_fc += 1
+            names.append(f"fc{(block - 1 if by_block else n_conv) + n_fc}")
+    return names
+
+
+def cnn_layer_scopes(cfg: CNNConfig) -> List[str]:
+    """``jax.named_scope`` of each layer in :func:`cnn_forward`: ``l<i>.<name>``.
+
+    ``i`` (two digits) is the layer's position in ``cfg.layers``, so a
+    profiler op under ``l02.conv1_2/...`` joins the layer's shape with no
+    name table.  The scopes reach only the HLO metadata, never the ops.
+    """
+    return [f"l{i:02d}.{n}" for i, n in enumerate(cnn_layer_names(cfg))]
+
+
 def cnn_init(cfg: CNNConfig, key, dtype=jnp.float32):
     params = []
     cin = cfg.in_channels
@@ -278,63 +314,65 @@ def cnn_forward(params, cfg: CNNConfig, x, plan=None, *, fuse=True):
     first_conv = True
     skip_pool = False        # the previous conv already pooled in-epilogue
     quant_after_pool = None  # unfused reference: quantize after pool2d
+    scopes = cnn_layer_scopes(cfg)
     for i, spec in enumerate(cfg.layers):
         p = params[i]
-        if spec[0] == "conv":
-            _, k, cout, stride = spec
-            padding = "VALID" if (cfg.name == "alexnet" and first_conv) else "SAME"
-            first_conv = False
-            path, block, fusion = cfg.conv_path, None, "bias_relu"
-            if use_plan and plan is not None \
-                    and (not int_policy or isinstance(p["w"], QWeight)):
-                ent = plan.lookup(kh=k, kw=k, stride=stride, h=x.shape[1],
-                                  cin=x.shape[3], cout=cout, padding=padding)
-                if ent is not None:
-                    path, block, fusion = ent.path, ent.block, ent.fusion
-            if isinstance(x, QActivation):
-                # A handoff input is an implicit-engine contract; the
-                # entry's block still applies when it planned implicit.
-                if path != "implicit":
-                    path, block = "implicit", None
-            do_pool = (fusion in ("pool", "pool_quant") and path == "implicit"
-                       and i + 1 < len(cfg.layers)
-                       and cfg.layers[i + 1] == ("pool",))
-            do_quant = (do_pool and fusion == "pool_quant" and int_policy
-                        and _handoff_consumer_ok(cfg, params, i))
-            # One fused call per conv layer: bias add + ReLU (and the dequant
-            # scale under integer policies) ride the conv epilogue instead of
-            # three HBM round-trips (DESIGN.md section 7.3).
-            if fuse and do_pool:
-                x = conv2d(x, p["w"], stride=stride, padding=padding,
-                           policy=cfg.policy, path=path, block=block,
-                           bias=p["b"], activation="relu",
-                           pool=(2, 2, "VALID"),
-                           quantize_next=spec_int[1] if do_quant else None)
-                skip_pool = True
+        with jax.named_scope(scopes[i]):
+            if spec[0] == "conv":
+                _, k, cout, stride = spec
+                padding = "VALID" if (cfg.name == "alexnet" and first_conv) else "SAME"
+                first_conv = False
+                path, block, fusion = cfg.conv_path, None, "bias_relu"
+                if use_plan and plan is not None \
+                        and (not int_policy or isinstance(p["w"], QWeight)):
+                    ent = plan.lookup(kh=k, kw=k, stride=stride, h=x.shape[1],
+                                      cin=x.shape[3], cout=cout, padding=padding)
+                    if ent is not None:
+                        path, block, fusion = ent.path, ent.block, ent.fusion
+                if isinstance(x, QActivation):
+                    # A handoff input is an implicit-engine contract; the
+                    # entry's block still applies when it planned implicit.
+                    if path != "implicit":
+                        path, block = "implicit", None
+                do_pool = (fusion in ("pool", "pool_quant") and path == "implicit"
+                           and i + 1 < len(cfg.layers)
+                           and cfg.layers[i + 1] == ("pool",))
+                do_quant = (do_pool and fusion == "pool_quant" and int_policy
+                            and _handoff_consumer_ok(cfg, params, i))
+                # One fused call per conv layer: bias add + ReLU (and the dequant
+                # scale under integer policies) ride the conv epilogue instead of
+                # three HBM round-trips (DESIGN.md section 7.3).
+                if fuse and do_pool:
+                    x = conv2d(x, p["w"], stride=stride, padding=padding,
+                               policy=cfg.policy, path=path, block=block,
+                               bias=p["b"], activation="relu",
+                               pool=(2, 2, "VALID"),
+                               quantize_next=spec_int[1] if do_quant else None)
+                    skip_pool = True
+                else:
+                    x = conv2d(x, p["w"], stride=stride, padding=padding,
+                               policy=cfg.policy, path=path, block=block,
+                               bias=p["b"], activation="relu")
+                    if do_pool and do_quant:
+                        quant_after_pool = spec_int[1]
+            elif spec[0] == "pool":
+                if skip_pool:
+                    skip_pool = False
+                else:
+                    x = pool2d(x, window=2, stride=2, kind="max")
+                    if quant_after_pool is not None:
+                        from repro.kernels.conv2d import handoff_quantize
+                        x = handoff_quantize(x, base_bits=quant_after_pool)
+                        quant_after_pool = None
             else:
-                x = conv2d(x, p["w"], stride=stride, padding=padding,
-                           policy=cfg.policy, path=path, block=block,
-                           bias=p["b"], activation="relu")
-                if do_pool and do_quant:
-                    quant_after_pool = spec_int[1]
-        elif spec[0] == "pool":
-            if skip_pool:
-                skip_pool = False
-            else:
-                x = pool2d(x, window=2, stride=2, kind="max")
-                if quant_after_pool is not None:
-                    from repro.kernels.conv2d import handoff_quantize
-                    x = handoff_quantize(x, base_bits=quant_after_pool)
-                    quant_after_pool = None
-        else:
-            if x.ndim == 4:
-                x = x.reshape(x.shape[0], -1)
-            x = policy_linear(x, p["w"], policy=cfg.policy) + p["b"]
-            # Positional check: every FC but the classifier head gets ReLU.
-            # (Comparing specs by VALUE would skip ReLU on any hidden FC whose
-            # spec equals the classifier's, e.g. duplicate ("fc", n) layers.)
-            if i != len(cfg.layers) - 1:
-                x = jax.nn.relu(x)
+                if x.ndim == 4:
+                    x = x.reshape(x.shape[0], -1)
+                x = policy_linear(x, p["w"], policy=cfg.policy) + p["b"]
+                # Positional check: every FC but the classifier head gets ReLU.
+                # (Comparing specs by VALUE would skip ReLU on any hidden FC whose
+                # spec equals the classifier's, e.g. duplicate ("fc", n) layers.)
+                if i != len(cfg.layers) - 1:
+                    x = jax.nn.relu(x)
     return x
 
 

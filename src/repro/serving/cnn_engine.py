@@ -73,6 +73,7 @@ from repro.core.substrate import policy_int_spec
 from repro.models.cnn import CNNConfig, cnn_forward, cnn_quantize_params
 from repro.serving.scheduler import (EngineDownError, IncompleteRunError,
                                      Microbatcher, RetryPolicy)
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass
@@ -111,7 +112,8 @@ class CNNServeEngine:
         if prequantize is None:
             prequantize = spec is not None
         if prequantize and spec is not None:
-            params = cnn_quantize_params(params, cfg)
+            with span("engine.quantize_weights"):
+                params = jax.block_until_ready(cnn_quantize_params(params, cfg))
         self.params = params
         # The whole-network ExecutionPlan, resolved ONCE at engine build
         # (explicit `plan` > committed benchmarks/tuned/plans/<backend>.json
@@ -122,7 +124,8 @@ class CNNServeEngine:
         self.plan = None
         if cfg.conv_path == "auto":
             from repro.core.planner import resolve_plan
-            self.plan = resolve_plan(cfg, plan)
+            with span("engine.plan"):
+                self.plan = resolve_plan(cfg, plan)
         elif plan is not None:
             raise ValueError(
                 f"explicit conv_path={cfg.conv_path!r} and an ExecutionPlan "
@@ -162,7 +165,8 @@ class CNNServeEngine:
         self.batcher = Microbatcher(buckets, slo_budgets=slo_budgets,
                                     retry=retry, advance=advance,
                                     on_fault=self._on_fault, **kw)
-        self._forward = jax.jit(self._make_forward())
+        with span("engine.jit"):
+            self._forward = jax.jit(self._make_forward())
         self._serve_fn = (self.faults.wrap(self._run_batch)
                           if self.faults is not None else self._run_batch)
 
@@ -277,36 +281,47 @@ class CNNServeEngine:
     # -- execution -----------------------------------------------------------
 
     def _run_batch(self, batch: np.ndarray) -> np.ndarray:
-        out = self._forward(self.params, jnp.asarray(batch))
-        return np.asarray(jax.block_until_ready(out))
+        with span("engine.to_device"):
+            x = jnp.asarray(batch)
+        with span("engine.forward"):
+            out = jax.block_until_ready(self._forward(self.params, x))
+        with span("engine.from_device"):
+            return np.asarray(out)
 
     def warmup(self) -> None:
         """Compile every bucket shape up front (steady-state = cache hits).
 
         Also seeds the batcher's per-bucket service-time history with a
         post-compile timed call per bucket, so the very first scheduling
-        decisions run the cost model instead of flying blind.
+        decisions run the cost model instead of flying blind.  Spans:
+        ``engine.warmup`` holds ``engine.warmup.b<bucket>.first`` (the
+        compile or persistent-cache load) and ``engine.warmup.b<bucket>.timed``
+        for each bucket.
         """
         import time as _time
 
         h, c = self.cfg.img_size, self.cfg.in_channels
-        for b in self.batcher.buckets:
-            zeros = jnp.zeros((b, h, h, c), jnp.float32)
-            jax.block_until_ready(self._forward(self.params, zeros))
-            t0 = _time.perf_counter()
-            jax.block_until_ready(self._forward(self.params, zeros))
-            self.batcher.record_service(b, _time.perf_counter() - t0)
+        with span("engine.warmup"):
+            for b in self.batcher.buckets:
+                zeros = jnp.zeros((b, h, h, c), jnp.float32)
+                with span(f"engine.warmup.b{b}.first"):
+                    jax.block_until_ready(self._forward(self.params, zeros))
+                with span(f"engine.warmup.b{b}.timed"):
+                    t0 = _time.perf_counter()
+                    jax.block_until_ready(self._forward(self.params, zeros))
+                    self.batcher.record_service(b, _time.perf_counter() - t0)
 
     def step(self) -> List[ImageRequest]:
         """Serve one microbatch; returns the requests completed by it."""
         if self.health == "down":
             raise EngineDownError(f"{self.cfg.name} engine is down")
         completed = self.batcher.step(self._serve_fn)
-        out = []
-        for req, logits in completed:
-            req.logits = logits
-            req.label = int(np.argmax(logits))
-            out.append(req)
+        with span("batch.finish"):
+            out = []
+            for req, logits in completed:
+                req.logits = logits
+                req.label = int(np.argmax(logits))
+                out.append(req)
         return out
 
     def run(self, max_steps: int = 10_000) -> Dict[int, ImageRequest]:

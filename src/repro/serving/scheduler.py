@@ -21,9 +21,10 @@ Scheduling is **continuous and SLO-aware**, not FIFO drain-to-empty:
     during) steps -- :meth:`Microbatcher.step` admits whatever is pending
     NOW, it never requires the queue to drain first;
   * bucket selection is a cost model, not a fixed rule: using the
-    per-bucket service-time history (``step_log``), :meth:`Microbatcher.
-    select_batch` trades padding fraction against the projected step time
-    so the most urgent pending deadline is still met (DESIGN.md 9.2).
+    per-bucket service-time history (each bucket's last ``HISTORY_WINDOW``
+    step times), :meth:`Microbatcher.select_batch` trades padding fraction
+    against the projected step time so the most urgent pending deadline is
+    still met (DESIGN.md 9.2).
 
 :class:`Microbatcher` keeps the fixed-shape discipline: the queue admits
 into a small set of batch *buckets* (e.g. 1/4/16/64), each microbatch
@@ -52,9 +53,13 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
+
+from repro.serving.spans import span
 
 #: Default latency budgets (seconds) per SLO class.  ``None`` = no deadline
 #: (best-effort batch work).  Engines and the queue accept an override dict.
@@ -569,14 +574,16 @@ class Microbatcher:
         self.real_rows = 0
         self.padded_rows = 0
         self.bucket_counts: Dict[int, int] = {b: 0 for b in self.buckets}
-        self.step_log: List[dict] = []
+        #: seconds spent in successful forwards (``stats()``'s busy time)
+        self.batch_seconds = 0.0
         # resilience bookkeeping
         self.retries = 0          # retried forward calls
         self.bisections = 0       # batch splits hunting a poison request
         self.quarantined = 0      # requests failed after exhausting attempts
         self.fault_counts: Dict[str, int] = {"transient": 0, "oom": 0}
         # per-bucket service-time history feeding the selection cost model
-        self._service_hist: Dict[int, List[float]] = {b: [] for b in self.buckets}
+        self._service_hist: Dict[int, Deque[float]] = {
+            b: deque(maxlen=self.HISTORY_WINDOW) for b in self.buckets}
 
     def submit(self, req, payload: np.ndarray, *,
                deadline: Optional[float] = None,
@@ -593,7 +600,8 @@ class Microbatcher:
         from ``warmup()`` so the very first scheduling decisions already
         have per-bucket timings instead of flying blind.
         """
-        self._service_hist.setdefault(bucket, []).append(float(seconds))
+        self._service_hist.setdefault(
+            bucket, deque(maxlen=self.HISTORY_WINDOW)).append(float(seconds))
 
     def service_estimate(self, bucket: int) -> Optional[float]:
         """Projected step time for ``bucket`` -- a p99-flavored bound.
@@ -608,9 +616,8 @@ class Microbatcher:
         """
         hist = self._service_hist.get(bucket)
         if hist:
-            return max(hist[-self.HISTORY_WINDOW:])
-        known = [(b, max(h[-self.HISTORY_WINDOW:]))
-                 for b, h in self._service_hist.items() if h]
+            return max(hist)
+        known = [(b, max(h)) for b, h in self._service_hist.items() if h]
         if not known:
             return None
         b0, t0 = min(known, key=lambda bt: abs(bt[0] - bucket))
@@ -702,12 +709,13 @@ class Microbatcher:
         re-queues the batch at the front and re-raises, exactly the
         pre-retry contract.
         """
-        now = self._clock()
-        self.queue.expire_overdue(now)
-        if len(self.queue) == 0:
-            return []
-        bucket, admit_n = self.select_batch(now)
-        admitted = self.queue.take(admit_n, order="edf")
+        with span("batch.admit"):
+            now = self._clock()
+            self.queue.expire_overdue(now)
+            if len(self.queue) == 0:
+                return []
+            bucket, admit_n = self.select_batch(now)
+            admitted = self.queue.take(admit_n, order="edf")
         return self._serve(admitted, run_batch, bucket=bucket)
 
     def _serve(self, admitted: List[Any], run_batch: Callable,
@@ -743,7 +751,8 @@ class Microbatcher:
                                     suspect=suspect)
                         + self._serve(admitted[mid:], run_batch,
                                       suspect=suspect))
-            batch = pad_batch([r._payload for r in admitted], bucket)
+            with span("batch.stack"):
+                batch = pad_batch([r._payload for r in admitted], bucket)
             uids = tuple(r.uid for r in admitted)
             t0 = self._clock()
             try:
@@ -818,19 +827,19 @@ class Microbatcher:
                 admitted = still
                 continue
             dt = self._clock() - t0
-            self.steps += 1
-            self.real_rows += len(admitted)
-            self.padded_rows += bucket - len(admitted)
-            self.bucket_counts[bucket] = \
-                self.bucket_counts.get(bucket, 0) + 1
-            self.step_log.append({"bucket": bucket, "real": len(admitted),
-                                  "seconds": dt})
-            self.record_service(bucket, dt)
-            results = []
-            for i, req in enumerate(admitted):
-                del req._payload  # long-lived engines must not retain inputs
-                self.queue.finish(req)
-                results.append((req, out[i]))
+            with span("batch.finish"):
+                self.steps += 1
+                self.real_rows += len(admitted)
+                self.padded_rows += bucket - len(admitted)
+                self.bucket_counts[bucket] = \
+                    self.bucket_counts.get(bucket, 0) + 1
+                self.batch_seconds += dt
+                self.record_service(bucket, dt)
+                results = []
+                for i, req in enumerate(admitted):
+                    del req._payload  # long-lived engines must not retain inputs
+                    self.queue.finish(req)
+                    results.append((req, out[i]))
             return results
 
     def run(self, run_batch: Callable[[np.ndarray], np.ndarray],
@@ -862,7 +871,7 @@ class Microbatcher:
 
     def stats(self) -> dict:
         lats = [v for v in self.queue.latencies() if v is not None]
-        wall = sum(s["seconds"] for s in self.step_log)
+        wall = self.batch_seconds
         met = [self.queue.timing[uid].met_deadline for uid in self.queue.done]
         misses = sum(1 for m in met if m is False)
         in_time = len(lats) - misses

@@ -175,7 +175,7 @@ def test_step_requeues_admitted_requests_on_forward_failure():
     # counters untouched by the failed step
     assert (mb.steps, mb.real_rows, mb.padded_rows) == (0, 0, 0)
     assert mb.bucket_counts == {1: 0, 4: 0}
-    assert mb.step_log == []
+    assert mb.batch_seconds == 0.0
     # admission stamp cleared: queue_wait will reflect the serving admission
     assert all(mb.queue.timing[u].admitted is None for u in range(4))
     # the retry succeeds and serves the SAME requests, oldest first
