@@ -31,8 +31,17 @@ discipline on the KOM substrate:
     bias + ReLU in the conv epilogue, DESIGN.md section 7.3); the engine
     needs no knowledge of the fusion and serves bitwise-identical logits to
     the unfused pipeline under the integer policies.
-  * **Data parallelism** -- pass a ``launch.mesh`` mesh and the batch axis
-    is sharded over its data axes via ``shard_map`` (params replicated);
+  * **Images put on the device at submit** -- ``submit`` copies each
+    image to the device as a flat float32 vector while fewer than
+    :attr:`~CNNServeEngine.stage_limit` requests wait (later ones are
+    copied when admitted, inside the step); the jitted forward stacks the
+    bucket's rows, with one resident zero image in every pad slot, and
+    reshapes them on the device.  So a step stacks no batch on the host
+    and waits on no batch copy; the copies at submit run on the caller's
+    thread between steps, not under them.
+  * **Data parallelism** -- pass a ``launch.mesh`` mesh and the batch axis,
+    stacked from the rows inside the jit, is sharded over its data axes
+    via ``shard_map`` (params replicated);
     buckets are rounded up to multiples of the data-parallel degree so
     every shard sees a full slice.  Unpadding/gather stays on host.
   * **Planned conv dispatch** -- the engine resolves a whole-network
@@ -71,8 +80,9 @@ import numpy as np
 
 from repro.core.substrate import policy_int_spec
 from repro.models.cnn import CNNConfig, cnn_forward, cnn_quantize_params
-from repro.serving.scheduler import (EngineDownError, IncompleteRunError,
-                                     Microbatcher, RetryPolicy)
+from repro.serving.scheduler import (BatchContractError, EngineDownError,
+                                     IncompleteRunError, Microbatcher,
+                                     RetryPolicy)
 from repro.serving.spans import span
 
 
@@ -162,9 +172,14 @@ class CNNServeEngine:
             # live in the same (warped) clock domain
             run_clock = inj.now
         kw = {} if run_clock is None else {"clock": run_clock}
+        h, c = cfg.img_size, cfg.in_channels
+        self._zero_row = jnp.zeros((h * h * c,), jnp.float32)
+        self.staged_rows = 0      # images put on the device at submit
+        self.late_rows = 0        # puts at admission (again on a retry)
         self.batcher = Microbatcher(buckets, slo_budgets=slo_budgets,
                                     retry=retry, advance=advance,
-                                    on_fault=self._on_fault, **kw)
+                                    on_fault=self._on_fault,
+                                    assemble=self._assemble, **kw)
         with span("engine.jit"):
             self._forward = jax.jit(self._make_forward())
         self._serve_fn = (self.faults.wrap(self._run_batch)
@@ -172,31 +187,55 @@ class CNNServeEngine:
 
     def _make_forward(self):
         cfg, plan = self.cfg, self.plan
+        shape = (-1, cfg.img_size, cfg.img_size, cfg.in_channels)
 
         def fwd(params, x):
             return cnn_forward(params, cfg, x, plan=plan)
 
-        if self.mesh is None:
-            return fwd
-        from jax.sharding import PartitionSpec as P
+        if self.mesh is not None:
+            from jax.sharding import PartitionSpec as P
 
-        from repro.launch.mesh import shard_map_unchecked
-        batch_spec = P(self._dp_axes, None, None, None)
-        # params replicated (P() prefix over the whole tree, QWeight leaves
-        # included); only the image batch axis is sharded.
-        return shard_map_unchecked(
-            fwd, self.mesh,
-            in_specs=(P(), batch_spec),
-            out_specs=P(self._dp_axes, None),
-        )
+            from repro.launch.mesh import shard_map_unchecked
+            batch_spec = P(self._dp_axes, None, None, None)
+            # params replicated (P() prefix over the whole tree, QWeight
+            # leaves included); only the image batch axis is sharded.
+            fwd = shard_map_unchecked(
+                fwd, self.mesh,
+                in_specs=(P(), batch_spec),
+                out_specs=P(self._dp_axes, None),
+            )
+
+        def forward(params, rows):
+            # the bucket's flat rows, stacked behind a barrier so the
+            # reshape lays the batch out whole (pushed into each row, it
+            # pads every row's size-1 batch axis to 128 lanes)
+            x = jax.lax.optimization_barrier(jnp.stack(rows))
+            return fwd(params, jnp.reshape(x, shape))
+
+        return forward
 
     @property
     def buckets(self) -> tuple:
         return self.batcher.buckets
 
+    @property
+    def stage_limit(self) -> int:
+        """Pending requests beyond which an image is put on the device only
+        when it is admitted: twice the largest bucket, so the next batch
+        and the one after it wait on the device while a deep backlog does
+        not."""
+        return 2 * self.batcher.buckets[-1]
+
     # -- admission -----------------------------------------------------------
 
     def submit(self, req: ImageRequest) -> None:
+        """Queue one image for serving.
+
+        The image is read at submit: it is copied to the device here,
+        unless :attr:`stage_limit` requests already wait, in which case it
+        is copied when the request is admitted.  Do not write into the
+        array after submitting it.
+        """
         if self.health == "down":
             raise EngineDownError(
                 f"{self.cfg.name} engine is down; submit to a healthy "
@@ -207,7 +246,11 @@ class CNNServeEngine:
             raise ValueError(
                 f"{self.cfg.name} serves ({h}, {h}, {self.cfg.in_channels}) "
                 f"images, got {img.shape}")
+        staged = len(self.batcher.queue) < self.stage_limit
+        if staged:
+            img = self._stage(img, "engine.stage")
         self.batcher.submit(req, img, deadline=req.deadline, slo=req.slo)
+        self.staged_rows += staged
 
     @property
     def expired(self):
@@ -276,15 +319,38 @@ class CNNServeEngine:
         still holds) and further submits raise :class:`EngineDownError`.
         """
         self.health = "down"
-        return self.batcher.queue.fail_pending(EngineDownError(reason))
+        return self.batcher.fail_pending(EngineDownError(reason))
 
     # -- execution -----------------------------------------------------------
 
-    def _run_batch(self, batch: np.ndarray) -> np.ndarray:
+    @staticmethod
+    def _stage(image: np.ndarray, name: str) -> jax.Array:
+        """Copy one image to the device, flat: the same float32 bytes, with
+        no 3-wide minor axis for the chip's tiling to pad."""
+        with span(name):
+            return jax.device_put(image.reshape(-1))
+
+    def _assemble(self, rows: list, bucket: int) -> tuple:
+        """The forward's batch: the admitted rows on the device (those
+        past the staging limit are put there now), then the resident zero
+        image in every pad slot -- one signature per bucket."""
+        if len(rows) > bucket:
+            raise BatchContractError(f"{len(rows)} rows exceed bucket {bucket}")
+        out = []
+        for row in rows:
+            if isinstance(row, np.ndarray):
+                row = self._stage(row, "engine.stage_late")
+                self.late_rows += 1
+            out.append(row)
+        return tuple(out) + (self._zero_row,) * (bucket - len(rows))
+
+    def _run_batch(self, rows: tuple) -> np.ndarray:
+        """One bucket through the jitted forward, once its rows' copies to
+        the device have landed."""
         with span("engine.to_device"):
-            x = jnp.asarray(batch)
+            rows = jax.block_until_ready(rows)
         with span("engine.forward"):
-            out = jax.block_until_ready(self._forward(self.params, x))
+            out = jax.block_until_ready(self._forward(self.params, rows))
         with span("engine.from_device"):
             return np.asarray(out)
 
@@ -300,10 +366,9 @@ class CNNServeEngine:
         """
         import time as _time
 
-        h, c = self.cfg.img_size, self.cfg.in_channels
         with span("engine.warmup"):
             for b in self.batcher.buckets:
-                zeros = jnp.zeros((b, h, h, c), jnp.float32)
+                zeros = self._assemble([], b)
                 with span(f"engine.warmup.b{b}.first"):
                     jax.block_until_ready(self._forward(self.params, zeros))
                 with span(f"engine.warmup.b{b}.timed"):
@@ -352,6 +417,8 @@ class CNNServeEngine:
         s["images_per_s"] = s.pop("throughput_rps")
         s["buckets"] = self.batcher.buckets
         s["data_parallel"] = self.dp
+        s["staged_rows"] = self.staged_rows
+        s["late_rows"] = self.late_rows
         s["health"] = self.health
         s["degrade_log"] = list(self.degrade_log)
         if self.faults is not None:

@@ -528,14 +528,18 @@ def pad_batch(rows: List[np.ndarray], bucket: int) -> np.ndarray:
 class Microbatcher:
     """SLO-aware continuous batching over a :class:`RequestQueue`.
 
-    Payloads (one ndarray per request, all the same shape) are stacked and
-    zero-padded to the selected bucket; the step fn sees only bucket-shaped
+    Payloads (one per request, all the same shape) are assembled into a
+    batch of the selected bucket -- by default stacked and zero-padded on
+    the host (:func:`pad_batch`); the step fn sees only bucket-shaped
     batches, and only the first ``n_real`` output rows are handed back to
-    their requests.  Admission is earliest-deadline-first and continuous --
-    submit between steps at will; each :meth:`step` first rejects overdue
-    requests (typed :class:`Expired` results), then picks the bucket whose
-    projected service time still meets the most urgent pending deadline at
-    the best real-rows-per-second (DESIGN.md 9.2).  Everything here is host
+    their requests.  An engine whose payloads are not host arrays (the CNN
+    engine's live on the device) passes its own ``assemble(payloads,
+    bucket)`` in place of :func:`pad_batch`.  Admission is
+    earliest-deadline-first and continuous -- submit between steps at will;
+    each :meth:`step` first rejects overdue requests (typed
+    :class:`Expired` results), then picks the bucket whose projected
+    service time still meets the most urgent pending deadline at the best
+    real-rows-per-second (DESIGN.md 9.2).  Everything here is host
     bookkeeping -- no device math -- so the scheduling policy is
     unit-testable with a stubbed forward fn.
     """
@@ -548,7 +552,8 @@ class Microbatcher:
                  slo_budgets: Optional[Dict[str, Optional[float]]] = None,
                  retry: Optional[RetryPolicy] = None,
                  advance: Optional[Callable[[float], None]] = None,
-                 on_fault: Optional[Callable] = None):
+                 on_fault: Optional[Callable] = None,
+                 assemble: Optional[Callable[[List[Any], int], Any]] = None):
         if not buckets:
             raise ValueError("need at least one bucket size")
         self.buckets = tuple(sorted(set(int(b) for b in buckets)))
@@ -569,6 +574,7 @@ class Microbatcher:
         #: aborts the batch -- its requests are failed typed, not retried
         #: (the engine went down)
         self._on_fault = on_fault
+        self._assemble = assemble or pad_batch
         # padding/throughput bookkeeping
         self.steps = 0
         self.real_rows = 0
@@ -585,11 +591,25 @@ class Microbatcher:
         self._service_hist: Dict[int, Deque[float]] = {
             b: deque(maxlen=self.HISTORY_WINDOW) for b in self.buckets}
 
-    def submit(self, req, payload: np.ndarray, *,
-               deadline: Optional[float] = None,
+    def submit(self, req, payload, *, deadline: Optional[float] = None,
                slo: Optional[str] = None) -> None:
-        req._payload = np.asarray(payload)
+        """Enqueue ``req`` with ``payload``, kept as given (no copy)."""
         self.queue.submit(req, deadline=deadline, slo=slo)
+        req._payload = payload
+
+    @staticmethod
+    def _release(req) -> None:
+        """Drop the payload of a request that is done, expired or failed:
+        a long-lived engine keeps no inputs, and a device payload holds
+        device memory."""
+        del req._payload
+
+    def fail_pending(self, error) -> List[Failed]:
+        """Fail every pending request (the engine is going down)."""
+        out = self.queue.fail_pending(error)
+        for res in out:
+            self._release(res.request)
+        return out
 
     # -- SLO-aware batch selection -------------------------------------------
 
@@ -711,7 +731,8 @@ class Microbatcher:
         """
         with span("batch.admit"):
             now = self._clock()
-            self.queue.expire_overdue(now)
+            for res in self.queue.expire_overdue(now):
+                self._release(res.request)
             if len(self.queue) == 0:
                 return []
             bucket, admit_n = self.select_batch(now)
@@ -752,7 +773,7 @@ class Microbatcher:
                         + self._serve(admitted[mid:], run_batch,
                                       suspect=suspect))
             with span("batch.stack"):
-                batch = pad_batch([r._payload for r in admitted], bucket)
+                batch = self._assemble([r._payload for r in admitted], bucket)
             uids = tuple(r.uid for r in admitted)
             t0 = self._clock()
             try:
@@ -780,6 +801,7 @@ class Microbatcher:
                     # further): terminal typed failures, no silent loss
                     for req in admitted:
                         self.queue.fail(req, error=exc, now=now)
+                        self._release(req)
                     return []
                 if self.retry is None:
                     # pre-retry contract: front-requeue + re-raise
@@ -792,6 +814,7 @@ class Microbatcher:
                         # exhausted its budget serving ALONE -- only now is
                         # the failure attributable to the request itself
                         self.queue.fail(req, error=exc, now=now)
+                        self._release(req)
                         self.quarantined += 1
                         return []
                 elif batch_failures >= (1 if suspect else
@@ -822,6 +845,7 @@ class Microbatcher:
                     d = self.queue.timing[req.uid].deadline
                     if d is not None and d <= now:
                         self.queue.expire(req, now)
+                        self._release(req)
                     else:
                         still.append(req)
                 admitted = still
@@ -837,7 +861,7 @@ class Microbatcher:
                 self.record_service(bucket, dt)
                 results = []
                 for i, req in enumerate(admitted):
-                    del req._payload  # long-lived engines must not retain inputs
+                    self._release(req)
                     self.queue.finish(req)
                     results.append((req, out[i]))
             return results

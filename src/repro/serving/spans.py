@@ -22,8 +22,9 @@ index of the enclosing span (its parent) in ``rec.spans`` or None, and
 whether its body raised.  While installed, the recorder also turns JAX's
 backend compiles (a compile or a persistent-cache load, both through
 ``jax.monitoring``) into ``jax.compile`` spans and Python's garbage
-collections (``gc.callbacks``) into ``python.gc`` spans, and counts them in
-``rec.counts`` with the persistent-cache hits (``jax.cache_hit``).
+collections (``gc.callbacks``) into ``python.gc`` spans.  ``rec.counts``
+tallies the spans by name, with the persistent-cache hits
+(``jax.cache_hit``).
 """
 from __future__ import annotations
 
@@ -66,6 +67,7 @@ class _Open:
         # records its own span first
         self.index = len(rec.spans)
         rec.spans.append(s)
+        rec.counts[self.name] = rec.counts.get(self.name, 0) + 1
         rec.stack.append(self.index)
         return self
 

@@ -163,6 +163,43 @@ def test_cnn_degraded_mode_stays_bitwise_then_goes_down():
         eng.submit(ImageRequest(uid=101, image=imgs[0]))
 
 
+def test_cnn_poison_among_staged_payloads_is_bisected_and_quarantined():
+    """Payloads staged on the device at submit, and past the staging limit
+    at admission: the poison request's batch is bisected, the poison is
+    quarantined holding no payload, and every batch-mate is served with
+    logits bitwise equal to a fault-free run."""
+    cfg = _cnn_cfg()
+    params = cnn_init(cfg, jax.random.PRNGKey(0))
+    n, poison = 12, 9      # bucket 4: the stage limit is 8, uids 8..11 late
+    imgs = _imgs(cfg, n)
+
+    ref = CNNServeEngine(cfg, params, buckets=(4,))
+    for uid in range(n):
+        ref.submit(ImageRequest(uid=uid, image=imgs[uid]))
+    ref_done = ref.run()
+
+    clk = _Clock()
+    eng = CNNServeEngine(cfg, params, buckets=(4,), clock=clk,
+                         faults=FaultPlan(seed=2, poison_uids=(poison,)),
+                         advance=clk.advance_to,
+                         retry=RetryPolicy(max_attempts=6, backoff_base=0.001))
+    for uid in range(n):
+        eng.submit(ImageRequest(uid=uid, image=imgs[uid]))
+    done = eng.run()
+
+    q = eng.batcher.queue
+    assert _conserved(q)
+    assert sorted(done) == [u for u in range(n) if u != poison]
+    assert list(q.failed) == [poison]
+    assert not hasattr(q.failed[poison].request, "_payload")
+    s = eng.stats()
+    assert s["bisections"] > 0 and s["quarantined"] == 1
+    # every retry of a batch puts its late rows on the device again
+    assert s["staged_rows"] == 8 and s["late_rows"] >= 4
+    for uid in done:
+        assert np.array_equal(done[uid].logits, ref_done[uid].logits), uid
+
+
 # -- LM engine under chaos ---------------------------------------------------
 
 def test_lm_engine_retries_and_quarantines():

@@ -43,12 +43,13 @@ class Clock:
 
 
 def _engine(buckets=(1, 4), forward=None, **kw):
-    """A reduced AlexNet engine whose forward is a stub (no compile)."""
+    """A reduced AlexNet engine whose forward is a stub (no compile); the
+    stub gets the bucket's rows, a tuple on one device."""
     cfg = reduced(get_config("alexnet")).replace(policy=MatmulPolicy.KOM_INT14)
     eng = CNNServeEngine(cfg, cnn_init(cfg, jax.random.PRNGKey(0)),
                          buckets=buckets, **kw)
     n = cfg.n_classes
-    eng._forward = forward or (lambda params, x: jnp.zeros((x.shape[0], n)))
+    eng._forward = forward or (lambda params, x: jnp.zeros((len(x), n)))
     return eng
 
 
@@ -100,7 +101,7 @@ def test_failed_forward_closes_its_spans_and_flags_them():
     def forward(params, x):
         if bool((np.asarray(x) == 3).any()):
             raise RuntimeError("poison row")
-        return jnp.zeros((x.shape[0], 16))
+        return jnp.zeros((len(x), 16))
 
     clock = Clock()
     eng = _engine(buckets=(1, 2, 4), forward=forward, clock=clock,
@@ -126,7 +127,7 @@ def test_failed_forward_closes_its_spans_and_flags_them():
 
 
 def test_new_bucket_shape_adds_one_compile_span():
-    jitted = jax.jit(lambda params, x: x.reshape(x.shape[0], -1)[:, :16])
+    jitted = jax.jit(lambda params, x: jnp.stack(x)[:, :16])
     eng = _engine(buckets=(1, 4), forward=jitted)
     _submit(eng, [0])
     eng.step()                                  # bucket 1 compiled here
@@ -162,7 +163,7 @@ def test_engine_build_and_warmup_spans():
     params = cnn_init(cfg, jax.random.PRNGKey(0))
     rec = spans.install(spans.Recorder())
     eng = CNNServeEngine(cfg, params, buckets=(1,))
-    eng._forward = lambda p, x: jnp.zeros((x.shape[0], cfg.n_classes))
+    eng._forward = lambda p, x: jnp.zeros((len(x), cfg.n_classes))
     eng.warmup()
     spans.uninstall()
     ours = [(i, s) for i, s in enumerate(rec.spans)
