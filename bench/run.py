@@ -6,12 +6,16 @@ Builds ``CNNServeEngine`` for the cell's configuration (``bench/configs``)
 with float weights made on the device from ``--seed``, warms up the
 buckets its traffic (``bench/traffic``) uses, drives the engine through
 ``submit``/``step`` for ``--seconds`` on the real clock, then checks a
-seeded sample of the served logits against the plain float32 reference
-(``bench/reference.py``).  The last line of standard output is one JSON
-object: ``correct``, ``attempted``, ``failed``, ``metrics`` (end-to-end with
-``--trace 0``, per-layer with ``--trace 1``), ``device``, with ``--trace 1``
-also ``breakdown``, and last ``checks``: each number compared beside its
-limit.  Each metric is computed by its own reader, ``bench/metrics/<name>.py``.
+seeded sample of the served logits against the plain float32 reference.
+Whatever depends on the network's shape (weights, reference, work, the
+program's config, the Pallas layers) is asked of the configuration's
+family, ``bench/families/<family>.py`` (``bench/family.py``).
+
+The last line of standard output is one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics`` (end-to-end with ``--trace 0``,
+per-layer with ``--trace 1``), ``device``, with ``--trace 1`` also
+``breakdown``, and last ``checks``: each number compared beside its limit.
+Each metric is computed by its own reader, ``bench/metrics/<name>.py``.
 
 Exits non-zero, printing no result, when JAX finds no TPU or fewer chips
 than the cell asks for.
@@ -44,6 +48,7 @@ sys.path.insert(1, str(ROOT / "src"))
 
 import numpy as np  # noqa: E402
 
+import family  # noqa: E402
 import loadgen  # noqa: E402
 import work  # noqa: E402
 
@@ -86,8 +91,8 @@ def assemble(spec: dict, w: dict) -> dict:
     per_layer = [m for m in spec["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
-    return {"workload": w, "config": config, "traffic": traffic,
-            "end_to_end": e2e, "per_layer": per_layer}
+    return {"workload": w, "config": config, "family": family.of(config),
+            "traffic": traffic, "end_to_end": e2e, "per_layer": per_layer}
 
 
 def reader(metric: str):
@@ -117,51 +122,17 @@ def devices_for(chips: int, require_chip: bool):
     return devs[:chips]
 
 
-def program_config(config: dict, policy: str):
-    """The program's config for ``config``, checked against the file."""
-    from repro.configs import get_config
-    from repro.core.precision import MatmulPolicy
-    from repro.models.cnn import cnn_conv_geometries
-
-    cfg = get_config(config["model"], policy=MatmulPolicy(policy),
-                     img_size=config["img_size"],
-                     in_channels=config["in_channels"],
-                     n_classes=config["n_classes"],
-                     layers=tuple(tuple(s) for s in config["layers"]))
-    pad = cnn_conv_geometries(cfg)[0]["padding"]
-    if pad != config["first_conv_padding"]:
-        raise ValueError(f"the program pads {config['model']}'s stem {pad}, "
-                         f"the configuration says "
-                         f"{config['first_conv_padding']}")
-    return cfg
-
-
-def pallas_layers(engine, config: dict) -> list:
-    """The conv layers that the engine's resolved plan puts on Pallas."""
-    from repro.models.cnn import cnn_conv_geometries
-
-    convs = [l for l in work.layers(config) if l.kind == "conv"]
-    out = []
-    for layer, g in zip(convs, cnn_conv_geometries(engine.cfg)):
-        ent = engine.plan.lookup(**g) if engine.plan is not None else None
-        path = ent.path if ent is not None else engine.cfg.conv_path
-        if path in ("systolic", "implicit", "winograd"):
-            out.append(layer)
-    return out
-
-
 def setup(c: dict, seed: int, *, policy: str | None = None):
     """Weights, engine and image pool; every bucket warmed up."""
     import jax
 
-    import reference
     from repro.serving.cnn_engine import CNNServeEngine
 
     # every program the run compiles goes to the cache, however quick
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    config = c["config"]
-    cfg = program_config(config, policy or config["policy"])
-    params = reference.init_params(config, seed)
+    config, fam = c["config"], c["family"]
+    cfg = fam.program_config(config, policy or config["policy"])
+    params = fam.init_params(config, seed)
     jax.block_until_ready(params)
     engine = CNNServeEngine(cfg, params, buckets=tuple(c["traffic"]["buckets"]))
     engine.warmup()
@@ -233,15 +204,17 @@ def measure(c: dict, ctx: dict, seed: int, seconds: float, *,
             "lost": loadgen.outstanding(w)}
 
 
-def compare(config: dict, params, images: np.ndarray, kept: dict) -> float:
-    """Worst max|served - reference| / max|reference| over the kept rows."""
+def compare(c: dict, params, images: np.ndarray, kept: dict) -> float:
+    """Worst max|served - reference| / max|reference| over the kept rows,
+    against cell ``c``'s family's reference."""
     import reference
 
     if not kept:
         return float("inf")
     uids = sorted(kept)
     idx = sorted({u % len(images) for u in uids})
-    ref = dict(zip(idx, reference.logits(params, config, images[idx])))
+    ref = dict(zip(idx, reference.logits(c["family"].forward, params,
+                                         c["config"], images[idx])))
     worst = 0.0
     for u in uids:
         r = ref[u % len(images)]
@@ -261,7 +234,7 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, trace: bool, *,
     dev = devices[0]
     ctx = setup(c, seed, policy=policy)
     setup_s = time.monotonic() - T_PROCESS
-    pallas = pallas_layers(ctx["engine"], c["config"])
+    pallas = c["family"].pallas_layers(ctx["engine"], c["config"])
     trace_dir = CACHE_DIR / "trace" / name if trace else None
     m = measure(c, ctx, seed, seconds, trace_dir=trace_dir)
     peak = _memory_peak(devices)
@@ -270,7 +243,7 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, trace: bool, *,
     # free the program's state before the reference runs on the chip
     del ctx["engine"]
     gc.collect()
-    err = compare(c["config"], ctx["params"], ctx["images"], kept)
+    err = compare(c, ctx["params"], ctx["images"], kept)
     limit = c["config"]["limit"]["max_rel_err"]
     checks = {
         # a reading that is no number (NaN logits, nothing kept) prints as null
